@@ -3,7 +3,8 @@
 Boots the ServingEngine on a reduced gemma2 config, serves a batch of
 requests with greedy decode, crashes mid-generation (dropping KV caches,
 the request hashmap, and the paged-LRU metadata), recovers from the
-persistent arena, and asserts the continued generations are identical.
+persistent arena, and asserts the continued generations equal those of
+an uninterrupted twin engine.
 
     PYTHONPATH=src python examples/serve_recover.py
 """
@@ -24,25 +25,31 @@ def main():
     model = build(cfg, compute_dtype=jnp.float32)
     params = model.init_params(jax.random.PRNGKey(0))
 
-    with tempfile.TemporaryDirectory() as td:
-        eng = ServingEngine(
-            model, params,
-            EngineConfig(max_batch=4, s_max=48, max_requests=32),
-            arena_path=os.path.join(td, "arena"))
+    rng = np.random.default_rng(7)
+    prompts = {rid: rng.integers(1, cfg.vocab, int(rng.integers(4, 9)))
+               for rid in (901, 902, 903)}
 
-        rng = np.random.default_rng(7)
-        prompts = {}
-        for rid in (901, 902, 903):
-            p = rng.integers(1, cfg.vocab, int(rng.integers(4, 9)))
-            prompts[rid] = p
-            eng.add_request(rid, p.astype(np.int64))
+    with tempfile.TemporaryDirectory() as td:
+        def engine(name):
+            eng = ServingEngine(
+                model, params,
+                EngineConfig(max_batch=4, s_max=48, max_requests=32),
+                arena_path=os.path.join(td, name))
+            for rid, p in prompts.items():
+                eng.add_request(rid, p.astype(np.int64))
+            return eng
+
+        eng = engine("arena")
+        for rid, p in prompts.items():
             print(f"request {rid}: prompt {p.tolist()}")
 
         print("\n-- serving 4 steps --")
         for i in range(4):
             print(f"step {i}: {eng.step()}")
 
-        expected = [eng.step() for _ in range(4)]
+        # an uninterrupted twin serves the same 8 steps
+        twin = engine("twin")
+        expected = [twin.step() for _ in range(8)][4:]
         print("\n-- CRASH: device caches + volatile host tables dropped --")
         eng.crash()
         dt = eng.recover()

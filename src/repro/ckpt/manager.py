@@ -216,8 +216,15 @@ class CheckpointManager:
         report.generation = step
         sd = state_spec._asdict()
         flat, treedef = jax.tree_util.tree_flatten_with_path(sd)
-        sflat = jax.tree.leaves(shardings) if shardings is not None \
-            else [None] * len(flat)
+        # flatten the shardings in sd's own (dict, key-sorted) order: a
+        # TrainState flattens in field order and would pair them with
+        # the wrong leaves
+        if shardings is None:
+            sflat = [None] * len(flat)
+        else:
+            if isinstance(shardings, TrainState):
+                shardings = shardings._asdict()
+            sflat = treedef.flatten_up_to(shardings)
         seed = None
         # first pass: essential scalars we need for reconstruction
         for pth, spec in flat:

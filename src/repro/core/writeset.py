@@ -443,13 +443,9 @@ class ShardedWriteSet:
 def _pack_gather(vol: np.ndarray, rows: np.ndarray) -> np.ndarray:
     """Gather dirty rows through the Pallas pack kernel (tile-aligned
     staging buffer — the §V-E flush-unit path).  Rows are bit-cast to
-    uint32 words so 64-bit payloads survive jax's default 32-bit mode.
-    Falls back to a numpy gather if the kernel stack is unavailable."""
-    try:
-        import jax.numpy as jnp
-        from repro.kernels import ops as kops
-    except Exception:                                 # pragma: no cover
-        return vol[rows]
+    uint32 words so 64-bit payloads survive jax's default 32-bit mode."""
+    import jax.numpy as jnp
+    from repro.kernels import ops as kops
     words = vol.reshape(vol.shape[0], -1).view(np.uint32)
     packed = kops.pack_rows(jnp.asarray(words), jnp.asarray(rows, jnp.int32))
     return np.ascontiguousarray(np.asarray(packed)).view(vol.dtype).reshape(

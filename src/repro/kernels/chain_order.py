@@ -94,7 +94,7 @@ def _double_kernel(jmp_ref, jump_at_ref, cnt_at_ref, cnt_ref,
 def jump_double(jump: jax.Array, cnt: jax.Array, *,
                 segments: Optional[np.ndarray] = None,
                 seg_rows: int = 0,
-                interpret: bool = True) -> Tuple[jax.Array, jax.Array]:
+                interpret: bool) -> Tuple[jax.Array, jax.Array]:
     """jump, cnt: (N,) int32.  Returns (jump', cnt') after one doubling
     round: jump'[i] = jump[jump[i]] (NULL absorbing), cnt'[i] = cnt[i] +
     cnt[jump[i]] for live lanes.  Out-of-range pointers terminate like
@@ -152,7 +152,7 @@ def _gather_kernel(steer_ref, val_at_ref, out):
 def gather_next(nxt: jax.Array, ids, *,
                 segments: Optional[np.ndarray] = None,
                 seg_rows: int = 0,
-                interpret: bool = True) -> jax.Array:
+                interpret: bool) -> jax.Array:
     """One contraction hop for a batch of lanes: out[i] = nxt[ids[i]]
     (NULL lanes stay NULL; out-of-range ids terminate, the shared
     torn-epoch contract).  ``nxt`` is the sanitized (n,) int32 pointer
@@ -199,7 +199,7 @@ def walk_segments(nxt: jax.Array, starts, *, k: int, head: int,
                   n_mult: int, promoted: bool,
                   segments: Optional[np.ndarray] = None,
                   seg_rows: int = 0, budget: int = 64,
-                  interpret: bool = True
+                  interpret: bool
                   ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Walk every lane's chain segment toward its next spine node in ONE
     ``pallas_call``: an in-kernel ``fori_loop`` takes up to ``budget``
@@ -249,9 +249,7 @@ def walk_segments(nxt: jax.Array, starts, *, k: int, head: int,
 
         def hop(_, st):
             cur, w, sp, done = st
-            nv = pl.load(nxt_ref,
-                         (pl.ds(pos(jnp.maximum(cur, 0)), 1),
-                          slice(None)))[0, 0]
+            nv = nxt_ref[pl.ds(pos(jnp.maximum(cur, 0)), 1), :][0, 0]
             live = jnp.logical_not(done)
             cur2 = jnp.where(live, nv, cur)
             w2 = jnp.where(live, w + 1, w)
@@ -293,7 +291,7 @@ def walk_segments(nxt: jax.Array, starts, *, k: int, head: int,
 def expand_segments(nxt: jax.Array, starts, posn, rem, count: int, *,
                     segments: Optional[np.ndarray] = None,
                     seg_rows: int = 0,
-                    interpret: bool = True) -> np.ndarray:
+                    interpret: bool) -> np.ndarray:
     """Emit every node of the used contraction segments into the final
     order array in ONE ``pallas_call``: lane i walks ``rem[i]`` hops
     from ``starts[i]``, storing each visited global id at
@@ -335,13 +333,9 @@ def expand_segments(nxt: jax.Array, starts, posn, rem, count: int, *,
         def hop(t, st):
             cur, p = st
             live = t < r
-            pl.store(out_ref,
-                     (pl.ds(jnp.where(live, p, p0), 1), slice(None)),
-                     jnp.full((1, 1), jnp.where(live, cur, g0),
-                              jnp.int32))
-            nv = pl.load(nxt_ref,
-                         (pl.ds(pos(jnp.maximum(cur, 0)), 1),
-                          slice(None)))[0, 0]
+            out_ref[pl.ds(jnp.where(live, p, p0), 1), :] = jnp.full(
+                (1, 1), jnp.where(live, cur, g0), jnp.int32)
+            nv = nxt_ref[pl.ds(pos(jnp.maximum(cur, 0)), 1), :][0, 0]
             return jnp.where(t + 1 < r, nv, cur), p + 1
 
         jax.lax.fori_loop(0, max_rem, hop, (g0, p0))
@@ -366,7 +360,7 @@ def expand_segments(nxt: jax.Array, starts, posn, rem, count: int, *,
 def chain_tables_device(nxt: np.ndarray, bits: int, *,
                         segments: Optional[np.ndarray] = None,
                         seg_rows: int = 0,
-                        interpret: bool = True
+                        interpret: bool
                         ) -> Tuple[List[np.ndarray], np.ndarray]:
     """Binary-lifting tables via the kernel: returns ([jump^(2^k) for
     k < bits], counts) with counts[i] = min(2^bits, chain length from i).
@@ -426,7 +420,7 @@ def chain_order_device(nxt: np.ndarray, head: int, *,
                        k: int = 0,
                        fuse: bool = True,
                        snapshot: Optional[ChainSnapshot] = None,
-                       interpret: bool = True) -> np.ndarray:
+                       interpret: bool) -> np.ndarray:
     """Full device-built chain order.  ``method`` — "double" (the
     doubling rounds run in the Pallas kernel; the final node-at-position
     extraction is a cheap O(count log count) gather off the returned

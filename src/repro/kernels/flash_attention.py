@@ -73,7 +73,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, block_q: int = 128,
                     block_k: int = 128, scale=None,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool) -> jax.Array:
     """q: (H, Sq, D); k, v: (H, Skv, D) — call via vmap/reshape for batch.
 
     Returns (H, Sq, D) in q's dtype.  Sq % block_q == Skv % block_k == 0.

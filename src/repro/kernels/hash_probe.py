@@ -35,7 +35,7 @@ def _probe_kernel(bid_ref, q_ref, keys_ref, out_ref):
 
 
 def probe(keys_table: jax.Array, queries: jax.Array, bucket_ids: jax.Array,
-          *, interpret: bool = True) -> jax.Array:
+          *, interpret: bool) -> jax.Array:
     """keys_table: (n_buckets, BUCKET) int32/int64-as-2xi32 packed keys;
     queries: (Q,) same dtype; bucket_ids: (Q,) int32.
     Returns (Q,) int32 global slot ids (-1 = absent)."""

@@ -2,8 +2,8 @@
 
 Handles: lane padding (last dim to 128/256 multiples), flattening arbitrary
 pytree leaves to (N, D) row form, backend selection (compiled on TPU,
-interpret elsewhere), and the leaf-level quantized-persist API used by the
-checkpoint manager.
+interpreted on CPU, refused elsewhere), and the leaf-level
+quantized-persist API used by the checkpoint manager.
 """
 from __future__ import annotations
 
@@ -19,7 +19,12 @@ from repro.kernels.quant_pack import GROUP
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Interpret the kernels on the CPU, compile them on a TPU; any other
+    backend has no kernel path and is refused rather than interpreted."""
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise RuntimeError(f"no Pallas kernel path for backend {backend!r}")
+    return backend == "cpu"
 
 
 def _pad_to(x: jax.Array, mult: int, axis: int) -> jax.Array:
@@ -62,7 +67,7 @@ def scatter_rows(dst: jax.Array, packed: jax.Array, idx: jax.Array,
 
 # ---------------- quantize / dequantize ----------------
 
-def _as_rows(x: jax.Array) -> Tuple[jax.Array, Tuple[int, ...], int]:
+def as_rows(x: jax.Array) -> Tuple[jax.Array, Tuple[int, ...], int]:
     """Flatten any leaf to (N, GROUP*k) rows, padding the tail."""
     flat = x.reshape(-1)
     n_el = flat.shape[0]
@@ -76,7 +81,7 @@ def _as_rows(x: jax.Array) -> Tuple[jax.Array, Tuple[int, ...], int]:
 @jax.jit
 def quantize_leaf(x: jax.Array):
     """Any-shaped float leaf -> (q int8 rows, scales, meta) for persist."""
-    rows, shape, n_el = _as_rows(x)
+    rows, shape, n_el = as_rows(x)
     q, s = quant_pack.quantize_blockwise(rows, interpret=_interpret())
     return q, s
 

@@ -14,6 +14,11 @@ idx == -1 are zero-filled).  The row index list is scalar-prefetched
 (pltpu.PrefetchScalarGridSpec) so BlockSpec index_maps can steer the input
 block choice — the idiomatic TPU dynamic-gather pattern.
 
+Rows move as (N, 1, D) views with a squeezed leading block dim: the TPU
+lowering requires a block's last two dims to be (8, 128) multiples or the
+full array dims, which a (1, bd) block of an (N, D) array is not, while the
+(1, bd) tail of an (N, 1, D) array is.
+
 scatter_unpack (restore path) is the exact inverse.
 """
 from __future__ import annotations
@@ -33,7 +38,8 @@ SUB = 8  # f32 sublane
 def _gather_kernel(idx_ref, src_ref, out_ref):
     """One grid step packs one output row-block from a dynamic source row.
 
-    grid = (n_out, D // bd); blocks: src (1, bd) selected by idx, out (1, bd).
+    grid = (n_out, D // bd); blocks: src (1, bd) selected by idx, out (1, bd)
+    (the squeezed (None, 1, bd) view of the (N, 1, D) arrays).
     """
     i = pl.program_id(0)
     valid = idx_ref[i] >= 0
@@ -42,7 +48,7 @@ def _gather_kernel(idx_ref, src_ref, out_ref):
 
 
 def pack_rows(src: jax.Array, idx: jax.Array, *, block_d: int = 512,
-              interpret: bool = True) -> jax.Array:
+              interpret: bool) -> jax.Array:
     """Gather rows of `src` (N, D) at `idx` (M,) into a packed (M, D) buffer.
 
     idx entries of -1 produce zero rows.  D must be a multiple of 128; the
@@ -58,17 +64,20 @@ def pack_rows(src: jax.Array, idx: jax.Array, *, block_d: int = 512,
         num_scalar_prefetch=1,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, bd),
-                         lambda i, j, idx_ref: (jnp.maximum(idx_ref[i], 0), j)),
+            pl.BlockSpec((None, 1, bd),
+                         lambda i, j, idx_ref: (jnp.maximum(idx_ref[i], 0),
+                                                0, j)),
         ],
-        out_specs=pl.BlockSpec((1, bd), lambda i, j, idx_ref: (i, j)),
+        out_specs=pl.BlockSpec((None, 1, bd),
+                               lambda i, j, idx_ref: (i, 0, j)),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _gather_kernel,
         grid_spec=spec,
-        out_shape=jax.ShapeDtypeStruct((m, d), src.dtype),
+        out_shape=jax.ShapeDtypeStruct((m, 1, d), src.dtype),
         interpret=interpret,
-    )(idx, src)
+    )(idx, src[:, None, :])
+    return out[:, 0, :]
 
 
 def _scatter_kernel(inv_ref, packed_ref, dst_ref, out_ref):
@@ -85,7 +94,7 @@ def _scatter_kernel(inv_ref, packed_ref, dst_ref, out_ref):
 
 
 def scatter_rows(dst: jax.Array, packed: jax.Array, idx: jax.Array, *,
-                 block_d: int = 512, interpret: bool = True) -> jax.Array:
+                 block_d: int = 512, interpret: bool) -> jax.Array:
     """Functional dst.at[idx[i]].set(packed[i]) for idx[i] >= 0 (restore).
 
     The (N,) inverse map (dst row -> packed row or -1) is computed with one
@@ -107,15 +116,18 @@ def scatter_rows(dst: jax.Array, packed: jax.Array, idx: jax.Array, *,
         num_scalar_prefetch=1,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, bd),
-                         lambda r, j, inv_ref: (jnp.maximum(inv_ref[r], 0), j)),
-            pl.BlockSpec((1, bd), lambda r, j, inv_ref: (r, j)),
+            pl.BlockSpec((None, 1, bd),
+                         lambda r, j, inv_ref: (jnp.maximum(inv_ref[r], 0),
+                                                0, j)),
+            pl.BlockSpec((None, 1, bd), lambda r, j, inv_ref: (r, 0, j)),
         ],
-        out_specs=pl.BlockSpec((1, bd), lambda r, j, inv_ref: (r, j)),
+        out_specs=pl.BlockSpec((None, 1, bd),
+                               lambda r, j, inv_ref: (r, 0, j)),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _scatter_kernel,
         grid_spec=spec,
-        out_shape=jax.ShapeDtypeStruct((n, d), dst.dtype),
+        out_shape=jax.ShapeDtypeStruct((n, 1, d), dst.dtype),
         interpret=interpret,
-    )(inv, packed, dst)
+    )(inv, packed[:, None, :], dst[:, None, :])
+    return out[:, 0, :]

@@ -6,10 +6,11 @@ bytes/elem — a ~3.9x reduction in flushed bytes (EXPERIMENTS.md §Perf).
 Also usable as the in-memory moment representation (8-bit Adam) for the
 llama4-400b memory budget (DESIGN.md §5).
 
-Tiling: grid over (N / bn, D / G) with G = group = 256.  Each (bn, G)
-block computes a per-row absmax -> scale column (bn, 1) and the quantized
-payload (bn, G).  All dims are multiples of (8, 128) so blocks sit on
-natural TPU tile boundaries.
+Tiling: grid over N / bn row blocks; each block spans the full width D,
+so the (bn, D / G) scales block covers its array's whole last dim (the TPU
+lowering refuses a (bn, 1) block there).  Inside the block a static loop
+over the D / G groups (G = group = 256, two lane tiles) computes each
+per-row absmax -> scale column and its (bn, G) quantized payload.
 """
 from __future__ import annotations
 
@@ -21,33 +22,39 @@ GROUP = 256
 
 
 def _quant_kernel(x_ref, q_ref, s_ref):
-    x = x_ref[...].astype(jnp.float32)            # (bn, G)
-    absmax = jnp.max(jnp.abs(x), axis=1, keepdims=True)
-    scale = jnp.maximum(absmax, 1e-12) / 127.0
-    q = jnp.clip(jnp.round(x / scale), -127, 127).astype(jnp.int8)
-    q_ref[...] = q
-    s_ref[...] = scale.astype(jnp.float32)
+    for g in range(x_ref.shape[1] // GROUP):
+        cols = slice(g * GROUP, (g + 1) * GROUP)
+        x = x_ref[:, cols].astype(jnp.float32)        # (bn, G)
+        absmax = jnp.max(jnp.abs(x), axis=1, keepdims=True)
+        scale = jnp.maximum(absmax, 1e-12) / 127.0
+        q_ref[:, cols] = jnp.clip(jnp.round(x / scale), -127,
+                                  127).astype(jnp.int8)
+        s_ref[:, g:g + 1] = scale
+
+
+def _row_block(n: int, block_n: int) -> int:
+    bn = min(block_n, n)
+    while n % bn:
+        bn //= 2
+    return bn
 
 
 def quantize_blockwise(x: jax.Array, *, block_n: int = 64,
-                       interpret: bool = True):
+                       interpret: bool):
     """x: (N, D) float -> (q (N, D) int8, scales (N, D // GROUP) f32).
 
     D must be a multiple of GROUP; N a multiple of 8 (ops.py pads).
     """
     n, d = x.shape
     assert d % GROUP == 0 and n % 8 == 0, (n, d)
-    bn = min(block_n, n)
-    while n % bn:
-        bn //= 2
-    grid = (n // bn, d // GROUP)
+    bn = _row_block(n, block_n)
     return pl.pallas_call(
         _quant_kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((bn, GROUP), lambda i, j: (i, j))],
+        grid=(n // bn,),
+        in_specs=[pl.BlockSpec((bn, d), lambda i: (i, 0))],
         out_specs=[
-            pl.BlockSpec((bn, GROUP), lambda i, j: (i, j)),
-            pl.BlockSpec((bn, 1), lambda i, j: (i, j)),
+            pl.BlockSpec((bn, d), lambda i: (i, 0)),
+            pl.BlockSpec((bn, d // GROUP), lambda i: (i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((n, d), jnp.int8),
@@ -58,27 +65,25 @@ def quantize_blockwise(x: jax.Array, *, block_n: int = 64,
 
 
 def _dequant_kernel(q_ref, s_ref, x_ref):
-    q = q_ref[...].astype(jnp.float32)
-    x_ref[...] = q * s_ref[...]
+    for g in range(q_ref.shape[1] // GROUP):
+        cols = slice(g * GROUP, (g + 1) * GROUP)
+        x_ref[:, cols] = q_ref[:, cols].astype(jnp.float32) * s_ref[:, g:g + 1]
 
 
 def dequantize_blockwise(q: jax.Array, scales: jax.Array, *,
                          block_n: int = 64, dtype=jnp.float32,
-                         interpret: bool = True) -> jax.Array:
+                         interpret: bool) -> jax.Array:
     n, d = q.shape
     assert d % GROUP == 0 and scales.shape == (n, d // GROUP)
-    bn = min(block_n, n)
-    while n % bn:
-        bn //= 2
-    grid = (n // bn, d // GROUP)
+    bn = _row_block(n, block_n)
     out = pl.pallas_call(
         _dequant_kernel,
-        grid=grid,
+        grid=(n // bn,),
         in_specs=[
-            pl.BlockSpec((bn, GROUP), lambda i, j: (i, j)),
-            pl.BlockSpec((bn, 1), lambda i, j: (i, j)),
+            pl.BlockSpec((bn, d), lambda i: (i, 0)),
+            pl.BlockSpec((bn, d // GROUP), lambda i: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((bn, GROUP), lambda i, j: (i, j)),
+        out_specs=pl.BlockSpec((bn, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, d), jnp.float32),
         interpret=interpret,
     )(q, scales)

@@ -22,6 +22,7 @@ import jax.numpy as jnp
 
 from repro.configs import base, registry
 from repro.core import policy as pol
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import build
 from repro.optim.adamw import AdamWConfig
 from repro.train.trainer import Trainer, TrainerConfig
@@ -55,6 +56,7 @@ def main() -> int:
     ap.add_argument("--resume", action="store_true")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = registry.get(args.arch)
     if args.reduced:
         cfg = base.reduced(cfg)

@@ -10,7 +10,11 @@ State classification (the paper's contract, applied to serving):
 
 The decode path runs a jit'd `decode_step` over fixed batch slots
 (slot-contiguous caches; the paged allocator manages page *metadata* —
-documented simplification, DESIGN.md §3).  Greedy sampling keeps recovery
+documented simplification, DESIGN.md §3).  A slot's device state is the
+model state after every logged token but the last: the next step feeds
+that last token at its own position, so admission and recovery both
+prefill ``log[:-1]`` and rebuild exactly the state an uninterrupted run
+holds.  Greedy sampling keeps recovery
 bit-checkable: tokens generated after recovery must equal an uninterrupted
 run, which tests/test_serving.py asserts.
 
@@ -154,8 +158,13 @@ class ServingEngine:
         self._admit_lock = threading.Lock()
         self._recover_concurrency = 1
         self._decode = jax.jit(model.decode_step)
-        self._prefill = jax.jit(lambda p, b: model.prefill(
-            p, b, s_max=cfg.s_max))
+
+        def prefill(params, batch):
+            return model.prefill(params, batch, s_max=cfg.s_max)
+
+        self._prefill = jax.jit(prefill)
+        # rid -> device logits of the last step() (read, never synced)
+        self.last_logits: Dict[int, jax.Array] = {}
         self.last_recovery: Optional[RecoveryReport] = None
         # rids lost to media corruption in the last salvage recovery:
         # admission refuses them (QuarantinedError) until readmit()
@@ -195,8 +204,9 @@ class ServingEngine:
                 self.journal.log(OP_ADMIT, rid,
                                  digest=args_digest(prompt), info=slot)
             self.arena.commit()
-        # DERIVABLE: device prefill into the slot
-        self._prefill_slot(slot, prompt)
+        # DERIVABLE: device state for all but the last prompt token,
+        # which the first step() feeds
+        self._prefill_slot(slot, np.asarray(prompt)[:-1])
         self.slot_rid[slot] = rid
         self.pos[slot] = plen
         return slot
@@ -210,8 +220,17 @@ class ServingEngine:
         single batched model call (tokens: (g, plen)), then scatter the
         (g, ...) cache rows into their slots with one indexed device
         update per cache leaf — the grouped re-prefill unit of the
-        batched recovery path."""
+        batched recovery path.  An empty prefix (one-token log) seats the
+        zero state every sequence starts from."""
         g = len(slots)
+        idx = jnp.asarray(slots, jnp.int32)
+        if tokens.shape[1] == 0:
+            kv = self.model.init_cache(g, self.cfg.s_max)
+            with self._cache_lock:
+                self.cache = _map_slot(
+                    self.cache, kv,
+                    lambda full, grp, ax: _scatter_batch(full, grp, idx, ax))
+            return
         batch = {"tokens": jnp.asarray(tokens, jnp.int32)}
         if self.model.cfg.family == "audio":
             batch["frames"] = jnp.zeros(
@@ -222,7 +241,6 @@ class ServingEngine:
                 (g, self.model.cfg.context_seq, self.model.cfg.d_model),
                 self.model.compute_dtype)
         _, kv = self._prefill(self.params, batch)
-        idx = jnp.asarray(slots, jnp.int32)
         # the model call above runs lock-free (groups prefill in
         # parallel under recover(concurrency>1)); the read-modify-write
         # scatter of the shared cache tree serializes
@@ -241,6 +259,7 @@ class ServingEngine:
         row and table entry flush once at the closing commit, not once
         per slot."""
         out: Dict[int, int] = {}
+        self.last_logits = {}
         with self.arena.epoch():
             for slot in range(self.cfg.max_batch):
                 rid = int(self.slot_rid[slot])
@@ -250,7 +269,7 @@ class ServingEngine:
                 if p >= self.cfg.s_max:
                     continue
                 last_tok = int(self.tok_region.read_one(slot, p - 1))
-                logits = self._decode_slot(slot, last_tok, p)
+                logits = self._decode_slot(slot, last_tok, p - 1)
                 tok = int(np.asarray(jnp.argmax(logits)))
                 # ESSENTIAL: append the generated token + bump lengths
                 self.tok_region.write_at(np.asarray([slot], np.int64),
@@ -263,6 +282,7 @@ class ServingEngine:
                 self.table.insert_batch(np.array([rid], np.int64), cur)
                 self.pos[slot] = p + 1
                 out[rid] = tok
+                self.last_logits[rid] = logits
             self.arena.commit()
         return out
 
@@ -475,7 +495,7 @@ def _reconstruct_engine(eng: "ServingEngine") -> dict:
         shard, tl = key
         sel = slots[(shards == shard) & (tlens == tl)]
         eng._prefill_slots(sel, np.asarray(
-            eng.tok_region.read_at(sel, slice(0, tl)), np.int32))
+            eng.tok_region.read_at(sel, slice(0, tl - 1)), np.int32))
         with eng._admit_lock:
             eng.slot_ready[sel] = True
             admitted = time.perf_counter() - t0

@@ -178,3 +178,20 @@ def test_elastic_restore_reshards(tmp_path):
     np.testing.assert_array_equal(np.asarray(got.params["w"]),
                                   np.asarray(st.params["w"]))
     assert got.params["w"].sharding.mesh.shape == {"data": 1, "model": 1}
+
+
+def test_restore_pairs_each_sharding_with_its_leaf(tmp_path):
+    """A TrainState of shardings flattens in field order, the state spec
+    in key order: restore must pair each leaf with its own sharding."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    st = tiny_state()
+    mgr = CheckpointManager(str(tmp_path), pol.PARTLY_PERSISTENT)
+    mgr.save(st)
+    mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
+    sh = jax.tree.map(lambda s: NamedSharding(mesh, P(*[None] * s.ndim)),
+                      state_spec(st))
+    got = mgr.restore(state_spec(st), shardings=sh)
+    for g, s, w in zip(jax.tree.leaves(got), jax.tree.leaves(sh),
+                       jax.tree.leaves(st)):
+        assert g.sharding == s
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))

@@ -13,6 +13,17 @@ from repro.kernels import (chain_order, hash_probe, ops, pack_flush,
 KEY = jax.random.PRNGKey(0)
 
 
+def test_interpret_only_on_cpu(monkeypatch):
+    """Kernels interpret on the CPU and compile on a TPU; any other
+    backend is refused instead of silently interpreted."""
+    assert ops._interpret() is True
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ops._interpret() is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="gpu"):
+        ops._interpret()
+
+
 # ---------------------------------------------------------------- pack
 
 @pytest.mark.parametrize("n,d", [(8, 128), (64, 256), (33, 384), (128, 512)])
@@ -425,7 +436,8 @@ def test_flash_attention_matches_model_blockwise():
                     ).reshape(b * nk * g, s, dh)
     vh = jnp.repeat(v.transpose(0, 2, 1, 3), g, axis=1
                     ).reshape(b * nk * g, s, dh)
-    got = flash_attention(qh, kh, vh, causal=True, block_q=64, block_k=64)
+    got = flash_attention(qh, kh, vh, causal=True, block_q=64, block_k=64,
+                          interpret=True)
     got = got.reshape(b, nk, g, s, dh).transpose(0, 3, 1, 2, 4)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=3e-5, rtol=3e-5)

@@ -325,13 +325,13 @@ def test_chain_order_snapshot_seed_device():
     head = int(perm[0])
     calls0 = co.KERNEL_CALLS
     s = ChainSnapshot(perm)
-    got = co.chain_order_device(nxt, head, snapshot=s)
+    got = co.chain_order_device(nxt, head, snapshot=s, interpret=True)
     np.testing.assert_array_equal(got, perm)
     assert s.outcome == "snapshot"
     assert co.KERNEL_CALLS - calls0 == 1     # one verify gather, no rank
     # a strict prefix must NOT be adopted (chain continues past it)
     s2 = ChainSnapshot(perm[:50])
-    got2 = co.chain_order_device(nxt, head, snapshot=s2)
+    got2 = co.chain_order_device(nxt, head, snapshot=s2, interpret=True)
     np.testing.assert_array_equal(got2, perm)
     assert s2.outcome != "snapshot" and s2.replayed == 200
 

@@ -105,6 +105,38 @@ def test_serving_crash_recover_determinism(tmp_path, small_model):
     assert ref == got
 
 
+@pytest.mark.parametrize("arch", ["llama3.2-3b", "hymba-1.5b"])
+def test_serving_one_token_prompt_recovers(tmp_path, arch):
+    """A one-token log has an empty prefix to prefill: admission and
+    recovery seat the zero state, and the recovered logits equal an
+    uninterrupted twin's (KV and recurrent state alike)."""
+    model = build(base.reduced(registry.get(arch)), compute_dtype=jnp.float32)
+    params = model.init_params(jax.random.PRNGKey(1))
+    ec = EngineConfig(max_batch=2, s_max=16, max_requests=8)
+
+    def fresh(name):
+        eng = ServingEngine(model, params, ec,
+                            arena_path=str(tmp_path / name))
+        eng.add_request(7, np.array([5], np.int64))
+        return eng
+
+    twin = fresh("twin")
+    eng = fresh("arena")
+    eng.crash()                                  # before any step
+    eng.recover()
+    for _ in range(2):
+        assert eng.step() == twin.step()
+        np.testing.assert_allclose(np.asarray(eng.last_logits[7]),
+                                   np.asarray(twin.last_logits[7]),
+                                   rtol=1e-5, atol=1e-5)
+    eng.crash()                                  # log of 3 tokens now
+    eng.recover()
+    assert eng.step() == twin.step()
+    np.testing.assert_allclose(np.asarray(eng.last_logits[7]),
+                               np.asarray(twin.last_logits[7]),
+                               rtol=1e-5, atol=1e-5)
+
+
 def test_paged_allocator_lru_and_recover(tmp_path):
     pa = PagedAllocator(PagedConfig(n_pages=16, page_tokens=4),
                         path=str(tmp_path / "pg"))
