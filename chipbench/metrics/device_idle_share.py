@@ -1,0 +1,9 @@
+"""1 - device-busy time over the traced window (union of the device's
+operations, trace), in %."""
+
+
+def read(run):
+    tr = run.trace
+    if tr is None or tr.window_s() <= 0:
+        return None
+    return 100.0 * (1.0 - tr.busy_s() / tr.window_s())
