@@ -55,6 +55,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.core.writeset import ShardedWriteSet, WriteSet
 
 LINE = 64                 # flush granularity (bytes) — paper's cache line
@@ -592,7 +593,12 @@ class Arena:
         flag bit).  Inside an epoch this flushes the pending marks first,
         so a commit never orders the flag ahead of its data.  In shadow
         mode the whole protocol collapses to ONE ordering point — see
-        ``_commit_shadow``."""
+        ``_commit_shadow``.  Spanned as ``persist.commit``
+        (``repro.obs``)."""
+        with obs.span("persist.commit"):
+            self._commit()
+
+    def _commit(self) -> None:
         if self.commit_mode == "shadow":
             self._commit_shadow()
             return
@@ -1707,7 +1713,12 @@ class ShardedArena:
         flip every shard's header and write the manifest last — the
         existing cross-shard atomicity protocol carries over unchanged.
         ``_crash_after_shard=-1`` crashes after the seals but before any
-        flip (the torn-flip window's leading edge)."""
+        flip (the torn-flip window's leading edge).  Spanned as
+        ``persist.commit`` (``repro.obs``)."""
+        with obs.span("persist.commit"):
+            self._commit(_crash_after_shard)
+
+    def _commit(self, _crash_after_shard: Optional[int]) -> None:
         if self.commit_mode == "shadow":
             if self.n_shards > 1:
                 list(self.pool().map(lambda sh: sh._shadow_collapse(),

@@ -62,6 +62,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.core import reconstruct
 from repro.core.arena import IntegrityError
 
@@ -574,7 +575,16 @@ class RecoveryReport:
     * ``wall_ms``          — what this pass actually took.
 
     ``total_seconds`` remains the wall-clock duration of the pass
-    (``wall_ms / 1000``) for existing call sites."""
+    (``wall_ms / 1000``) for existing call sites.
+
+    A stage's seconds are host time.  Where a reconstructor dispatches
+    device work, its stage ends at dispatch, not when the device is
+    done: the serving engine's ``engine`` stage ends once its grouped
+    re-prefills and cache scatters are enqueued, and the benchmark's
+    ``recovery.rebuild_device_ms`` reads their device time from a trace.
+    No stage waits for the device, which would add a sync to the first
+    token after a crash.  Under the JAX profiler each stage is also a
+    ``recover.<name>`` span (``repro.obs``) over the same interval."""
     valid: bool = True
     generation: int = 0
     total_seconds: float = 0.0
@@ -791,27 +801,30 @@ class RecoveryManager:
 
         reopen_secs = 0.0
         if reopen and self.arenas:
-            t0 = time.perf_counter()
-            valids = []
-            for a in self.arenas:
-                if getattr(a, "n_shards", 1) > 1:
-                    # pooled shard reload of whatever the load stages
-                    # below don't cover (GIL-releasing block copies)
-                    a.reopen(concurrency=report.concurrency,
-                             exclude=tuple(
-                                 n for n, rs in split.items()
-                                 if any(r.arena is a for r in rs)))
-                else:
-                    a.reopen()
-                # garbage header/manifest magic is media corruption no
-                # power loss can produce — fail typed (ManifestError)
-                # before trusting the generation it claims, salvage or
-                # not (with no trustworthy generation there is no
-                # committed prefix to salvage toward)
-                if hasattr(a, "verify_header"):
-                    a.verify_header()
-                valids.append(bool(a.header_valid()))
-            reopen_secs = time.perf_counter() - t0
+            with obs.span("recover.reopen") as sp:
+                t0 = time.perf_counter()
+                valids = []
+                for a in self.arenas:
+                    if getattr(a, "n_shards", 1) > 1:
+                        # pooled shard reload of whatever the load stages
+                        # below don't cover (GIL-releasing block copies)
+                        a.reopen(concurrency=report.concurrency,
+                                 exclude=tuple(
+                                     n for n, rs in split.items()
+                                     if any(r.arena is a for r in rs)))
+                    else:
+                        a.reopen()
+                    # garbage header/manifest magic is media corruption
+                    # no power loss can produce — fail typed
+                    # (ManifestError) before trusting the generation it
+                    # claims, salvage or not (with no trustworthy
+                    # generation there is no committed prefix to salvage
+                    # toward)
+                    if hasattr(a, "verify_header"):
+                        a.verify_header()
+                    valids.append(bool(a.header_valid()))
+                reopen_secs = time.perf_counter() - t0
+                sp.clock(t0, t0 + reopen_secs)
             st = report.add("reopen", reopen_secs,
                             arenas=len(self.arenas), valid=valids,
                             shards=[getattr(a, "n_shards", 1)
@@ -822,7 +835,8 @@ class RecoveryManager:
                             # discarded orphans from any torn flip (§9)
                             modes=[getattr(a, "commit_mode", "barrier")
                                    for a in self.arenas])
-            st.t_start, st.t_end = 0.0, reopen_secs
+            st.t_start = t0 - t_all
+            st.t_end = st.t_start + reopen_secs
             report.valid = all(valids)
             # the committed (persisted) generation — survives recovery in
             # a fresh process, unlike the in-memory commit counter
@@ -867,6 +881,12 @@ class RecoveryManager:
                     sh._salvage = True
 
         def run_stage(name: str) -> StageReport:
+            with obs.span("recover." + name) as sp:
+                st = timed_stage(name)
+                sp.clock(t_all + st.t_start, t_all + st.t_end)
+            return st
+
+        def timed_stage(name: str) -> StageReport:
             t0 = time.perf_counter()
             faults0 = _cache_faults() if caches else 0
             bad_deps = sorted(d for d in depends_of.get(name, ())

@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import reconstruct as rec
 from repro.core.arena import (CorruptLineError, QuarantinedError,
                               journal_enabled, open_arena)
@@ -260,7 +261,7 @@ class ServingEngine:
         per slot."""
         out: Dict[int, int] = {}
         self.last_logits = {}
-        with self.arena.epoch():
+        with obs.span("serve.step"), self.arena.epoch():
             for slot in range(self.cfg.max_batch):
                 rid = int(self.slot_rid[slot])
                 if rid < 0 or not self.slot_ready[slot]:
@@ -268,23 +269,32 @@ class ServingEngine:
                 p = int(self.pos[slot])
                 if p >= self.cfg.s_max:
                     continue
-                last_tok = int(self.tok_region.read_one(slot, p - 1))
+                with obs.span("persist.record", rid):
+                    last_tok = int(self.tok_region.read_one(slot, p - 1))
                 logits = self._decode_slot(slot, last_tok, p - 1)
-                tok = int(np.asarray(jnp.argmax(logits)))
+                tok = self._host_token(logits, rid)
                 # ESSENTIAL: append the generated token + bump lengths
-                self.tok_region.write_at(np.asarray([slot], np.int64),
-                                         p, tok)
-                self.tok_region.mark_range(slot, slot + 1)
-                val = np.zeros((1, 7), np.int64)
-                val[0, :4] = [slot, 0, 0, 1]
-                ok, cur = self.table.find_batch(np.array([rid], np.int64))
-                cur[0, V_TLEN] += 1
-                self.table.insert_batch(np.array([rid], np.int64), cur)
+                with obs.span("persist.record", rid):
+                    self.tok_region.write_at(np.asarray([slot], np.int64),
+                                             p, tok)
+                    self.tok_region.mark_range(slot, slot + 1)
+                    ok, cur = self.table.find_batch(
+                        np.array([rid], np.int64))
+                    cur[0, V_TLEN] += 1
+                    self.table.insert_batch(np.array([rid], np.int64), cur)
                 self.pos[slot] = p + 1
                 out[rid] = tok
                 self.last_logits[rid] = logits
             self.arena.commit()
         return out
+
+    @staticmethod
+    def _host_token(logits, rid: int) -> int:
+        """The greedy token on the host: the step path's one read of a
+        device value, which waits out the slot's decode.  Every such
+        read goes through here, inside a ``serve.sync`` span."""
+        with obs.span("serve.sync", rid):
+            return int(np.asarray(jnp.argmax(logits)))
 
     def finish_request(self, rid: int) -> int:
         """Retire a completed request: journal the completion and
@@ -319,22 +329,26 @@ class ServingEngine:
         # lose a sibling prefill group's scatter during early-admission
         # decoding (step() inside an on_slot_ready callback while
         # recovery is still prefilling other slots)
-        one = _map_slot(
-            self.cache, self.cache,
-            lambda full, _, ax: jax.lax.dynamic_slice_in_dim(
-                full, slot, 1, axis=ax))
-        logits, one2 = self._decode(self.params, one,
-                                    jnp.asarray([token], jnp.int32),
-                                    jnp.asarray(p, jnp.int32))
+        rid = int(self.slot_rid[slot])
+        with obs.span("serve.slot.extract", rid):
+            one = _map_slot(
+                self.cache, self.cache,
+                lambda full, _, ax: jax.lax.dynamic_slice_in_dim(
+                    full, slot, 1, axis=ax))
+        with obs.span("serve.slot.decode", rid):
+            logits, one2 = self._decode(self.params, one,
+                                        jnp.asarray([token], jnp.int32),
+                                        jnp.asarray(p, jnp.int32))
+            logits = logits[0]
         # the cache updates ONLY here, inside the lock — returning it for
         # reassignment at the call site would re-introduce the lost-update
         # window this lock closes
-        with self._cache_lock:
+        with obs.span("serve.slot.reseat", rid), self._cache_lock:
             self.cache = _map_slot(
                 self.cache, one2,
                 lambda full, o, ax: jax.lax.dynamic_update_slice_in_dim(
                     full, o.astype(full.dtype), slot, axis=ax))
-        return logits[0]
+        return logits
 
     # ------------------------------------------------------------------
     def crash(self) -> None:
@@ -431,7 +445,12 @@ def _reconstruct_engine(eng: "ServingEngine") -> dict:
     ``n_shards=1`` the grouping degenerates to the per-length grouping
     exactly.  Groups run in a thread pool when the engine is recovering
     with ``concurrency>1`` (model calls parallel, cache scatter
-    serialized by the cache lock)."""
+    serialized by the cache lock).
+
+    The stage's seconds end at dispatch: nothing here waits for the
+    prefills and scatters to finish on the device (the first ``step()``
+    does).  Their device time is ``recovery.rebuild_device_ms`` in the
+    benchmark, read from a trace after the ``recover.engine`` span."""
     cfg = eng.cfg
     t0 = time.perf_counter()
     eng.cache = eng.model.init_cache(cfg.max_batch, cfg.s_max)
