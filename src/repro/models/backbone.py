@@ -155,6 +155,23 @@ def layer_shapes(cfg: ArchConfig, tag: str) -> Dict[str, Any]:
     return sh
 
 
+# Leaf names the forward reads only through ``.astype(compute_dtype)``, as an
+# operand of a dot or a gather, wherever they appear in the tree: a serving
+# copy may hold them in the compute dtype (``Model.compute_params``).  Every
+# other leaf is read in f32 and keeps its own dtype: the norm gains, the MoE
+# router, the SSM's conv/dt_w/dt_bias/a_log/d_skip, the biases b_if and b_in,
+# xgate and the sLSTM recurrence r.  tests/test_compute_params.py holds every
+# registry architecture to this table.
+MATMUL_WEIGHTS = frozenset({
+    "embed", "lm_head",
+    "wq", "wk", "wv", "wo",                       # attention, xattn, xLSTM
+    "w_gate", "w_up", "w_down",                   # dense MLP and MoE experts
+    "s_gate", "s_up", "s_down",                   # MoE shared expert
+    "in_proj", "x_proj", "w_mamba_out",           # hybrid's SSM branch
+    "w_if", "w_og", "w_in",                       # mLSTM gates, sLSTM input
+})
+
+
 def _leaf_specs(tree, prefix_dims=()):
     return jax.tree.map(
         lambda s: jax.ShapeDtypeStruct(tuple(prefix_dims) + tuple(s), jnp.float32),

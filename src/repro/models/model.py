@@ -21,6 +21,11 @@ Array = jax.Array
 PyTree = Any
 
 
+@functools.partial(jax.jit, static_argnums=1)
+def _cast(leaves, dtype):
+    return [x.astype(dtype) for x in leaves]
+
+
 def _mask_padded_vocab(logits: Array, vocab: int) -> Array:
     vp = logits.shape[-1]
     if vp == vocab:
@@ -42,6 +47,30 @@ class Model:
 
     def init_params(self, rng: jax.Array) -> PyTree:
         return B.init_params(self.cfg, rng)
+
+    def compute_params(self, params: PyTree) -> Tuple[PyTree, Dict[str, int]]:
+        """``params`` with every matmul weight (``backbone.MATMUL_WEIGHTS``)
+        cast to the compute dtype in one jitted call, and the bytes of the
+        result that were cast (``cast_bytes``) and kept as they were
+        (``kept_bytes``).  Every other leaf, and a weight already in the
+        compute dtype, is the same array object.  The forward casts those
+        weights to the compute dtype on every read, so it computes the
+        same logits from either tree; from this one it skips reading the
+        f32 weights and writing their cast in each program call."""
+        dt = jnp.dtype(self.compute_dtype)
+        flat, treedef = jax.tree_util.tree_flatten_with_path(params)
+        leaves = [x for _, x in flat]
+        picked = [i for i, (path, x) in enumerate(flat)
+                  if getattr(path[-1], "key", None) in B.MATMUL_WEIGHTS
+                  and x.dtype != dt]
+        if picked:
+            cast = _cast([leaves[i] for i in picked], dt)
+            for i, y in zip(picked, cast):
+                leaves[i] = y
+        cast_bytes = sum(leaves[i].nbytes for i in picked)
+        total = sum(x.nbytes for x in leaves)
+        return (jax.tree.unflatten(treedef, leaves),
+                {"cast_bytes": cast_bytes, "kept_bytes": total - cast_bytes})
 
     def cache_specs(self, batch: int, s_max: int) -> PyTree:
         return B.cache_specs(self.cfg, batch, s_max, self.compute_dtype)

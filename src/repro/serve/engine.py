@@ -7,6 +7,9 @@ State classification (the paper's contract, applied to serving):
   rebuilt by re-prefilling the persisted token log after a crash; the
   paged-LRU metadata reconstructs from its persistent NEXT chain
   (kvcache.PagedAllocator).
+Weights are not session state: the engine serves a compute-dtype copy of
+the matmul weights (``Model.compute_params``), made once at construction,
+and a crash keeps it.
 
 The decode path runs a jit'd `decode_step` over fixed batch slots
 (slot-contiguous caches; the paged allocator manages page *metadata* —
@@ -107,7 +110,8 @@ class ServingEngine:
     def __init__(self, model: Model, params, cfg: EngineConfig,
                  arena_path: Optional[str] = None):
         self.model = model
-        self.params = params
+        # matmul weights cast once here, not in every prefill and decode
+        self.params, _ = model.compute_params(params)
         self.cfg = cfg
         layout = dict(Hashmap.layout(cfg.max_requests, cfg.mode, name="req",
                                      snapshot=cfg.snapshot))
