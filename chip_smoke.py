@@ -167,7 +167,7 @@ def _time_calls(obj, name: str) -> list:
 def serve_and_recover(*, full_size: bool, seed: int, workdir: Path,
                       compiles: CompileLog,
                       prompt_lens=PROMPT_LENS, s_max: int = S_MAX):
-    """The one-chip serving phase; returns the model parameters."""
+    """The one-chip serving phase; returns the model and its parameters."""
     from repro.launch import serve
 
     compiles.phase = "init"
@@ -223,7 +223,7 @@ def serve_and_recover(*, full_size: bool, seed: int, workdir: Path,
     log(f"  one B=1 decode_step program alone (a step runs {MAX_BATCH}): "
         f"{secs} s")
     compare_to_twin(got, ref, model.cfg.vocab, crash_at=STEPS)
-    return params
+    return model, params
 
 
 # ------------------------------------------------------------- kernels
@@ -232,13 +232,20 @@ def _custom_call_compiled(fn, *args) -> bool:
     return "tpu_custom_call" in fn.lower(*args).compile().as_text()
 
 
-def kernel_phase(params, seed: int) -> None:
+def ffn_leaf(cfg, params):
+    """The first layer's MLP up-projection, (d_model, d_ff)."""
+    from repro.models import backbone
+
+    return next(backbone.layer_params(cfg, params))["mlp"]["w_up"]
+
+
+def kernel_phase(cfg, params, seed: int) -> None:
     """The checkpoint quantizer on a real-width hymba FFN leaf and the
     write-set pack/scatter kernels, compiled for the chip, against the
     pure-jnp references."""
     from repro.kernels import ops, ref
 
-    leaf = params["blocks"]["pos0"]["mlp"]["w_up"][0]        # (1600, 5504)
+    leaf = ffn_leaf(cfg, params)                             # (1600, 5504)
     assert _custom_call_compiled(ops.quantize_leaf, leaf)
     dequant = jax.jit(ops.dequantize_leaf, static_argnums=(2, 3))
     q, s = ops.quantize_leaf(leaf)
@@ -367,11 +374,11 @@ def main(argv=None) -> int:
             if args.chips == 4:
                 reshard_phase(devices, Path(td))
             else:
-                params = serve_and_recover(full_size=True, seed=args.seed,
-                                           workdir=Path(td),
-                                           compiles=compiles)
+                model, params = serve_and_recover(
+                    full_size=True, seed=args.seed, workdir=Path(td),
+                    compiles=compiles)
                 compiles.phase = "kernels"
-                kernel_phase(params, args.seed)
+                kernel_phase(model.cfg, params, args.seed)
     finally:
         jax.monitoring.unregister_event_duration_listener(compiles)
     compiles.report()

@@ -28,6 +28,14 @@ DENSE = "dense"            # plain attn mixer + dense FFN
 
 RECURRENT_TYPES = (HYBRID, MLSTM, SLSTM)
 
+# Suffix of a tag whose layer projects only Q and attends over the keys and
+# values of the layer before it that holds them (a KV-sharing group).
+SHARED_KV = ":shared"
+
+
+def shares_kv(tag: str) -> bool:
+    return tag.endswith(SHARED_KV)
+
 
 def _round_up(x: int, mult: int) -> int:
     return ((x + mult - 1) // mult) * mult
@@ -48,6 +56,7 @@ class SSMConfig:
     state_dim: int = 16           # N, per-channel SSM state
     conv_width: int = 4
     expand: int = 1               # d_inner = expand * d_model
+    dt_rank: int = 0              # 0 -> d_model // 16
 
 
 @dataclasses.dataclass(frozen=True)
@@ -69,9 +78,15 @@ class ArchConfig:
     d_ff: int
     vocab: int
     head_dim: int = 0             # 0 -> d_model // n_heads
-    # Attention pattern.
+    v_head_mult: int = 1          # value head size / q/k head size
+    # Attention pattern.  A stack with KV-sharing groups (``SHARED_KV``
+    # tags) lists every layer's tag, and is scanned as runs of equal
+    # groups (``stack_runs``); any other pattern is a tiled superblock.
     layer_pattern: Tuple[str, ...] = (DENSE,)
     window: int = 1024            # sliding window for ATTN_LOCAL layers
+    # Learned tokens prepended to every sequence, always attended to, also
+    # by sliding-window layers (hymba's meta tokens).
+    meta_tokens: int = 0
     rope_theta: float = 10000.0
     attn_softcap: float = 0.0     # gemma2-style tanh softcap on logits
     final_softcap: float = 0.0    # softcap on LM logits
@@ -95,6 +110,40 @@ class ArchConfig:
         return self.head_dim or (self.d_model // self.n_heads)
 
     @property
+    def v_head_dim(self) -> int:
+        return self.v_head_mult * self.resolved_head_dim
+
+    # Flat views of the SSM geometry (benchmark configs compare them).
+    @property
+    def d_inner(self) -> int:
+        return self.ssm.expand * self.d_model if self.ssm else 0
+
+    @property
+    def ssm_state(self) -> int:
+        return self.ssm.state_dim if self.ssm else 0
+
+    @property
+    def conv_width(self) -> int:
+        return self.ssm.conv_width if self.ssm else 0
+
+    @property
+    def dt_rank(self) -> int:
+        if not self.ssm:
+            return 0
+        return self.ssm.dt_rank or max(1, self.d_model // 16)
+
+    @property
+    def kv_source(self) -> Tuple[int, ...]:
+        """The layer whose keys and values each layer attends over: itself,
+        or for a ``SHARED_KV`` layer the last layer before it that is not
+        one."""
+        out = []
+        for i in range(self.n_layers):
+            tag = self.layer_pattern[i % len(self.layer_pattern)]
+            out.append(out[-1] if shares_kv(tag) else i)
+        return tuple(out)
+
+    @property
     def vocab_padded(self) -> int:
         """Embedding tables are padded to a multiple of 256 so the vocab
         dimension shards evenly over a 16-way model axis (standard practice;
@@ -112,6 +161,33 @@ class ArchConfig:
         n_super = self.n_layers // len(p)
         rem = tuple(p[: self.n_layers % len(p)])
         return p, n_super, rem
+
+    def stack_runs(self) -> Tuple[Tuple[Tuple[str, ...], int], ...]:
+        """A KV-sharing stack as ``((period, repeats), ...)`` in layer
+        order: each period is one group (a layer and the ``SHARED_KV``
+        layers after it), and consecutive equal groups form one run, so
+        that a group never straddles a scan step.  Empty for a stack
+        without sharing (``pattern_plan`` tiles it)."""
+        if not any(shares_kv(t) for t in self.layer_pattern):
+            return ()
+        if len(self.layer_pattern) != self.n_layers:
+            raise ValueError(f"{self.name}: a KV-sharing layer_pattern "
+                             "lists every layer")
+        if shares_kv(self.layer_pattern[0]):
+            raise ValueError(f"{self.name}: layer 0 has no KV to share")
+        groups: list = []
+        for t in self.layer_pattern:
+            if shares_kv(t):
+                groups[-1].append(t)
+            else:
+                groups.append([t])
+        runs: list = []
+        for g in map(tuple, groups):
+            if runs and runs[-1][0] == g:
+                runs[-1][1] += 1
+            else:
+                runs.append([g, 1])
+        return tuple((g, n) for g, n in runs)
 
     # ---- analytical parameter / FLOP accounting (for roofline ratios) ----
     def param_count(self) -> int:
@@ -152,10 +228,15 @@ def reduced(cfg: ArchConfig) -> ArchConfig:
     """A tiny same-family config for CPU smoke tests.
 
     Keeps the layer_pattern (one full superblock + remainder coverage), cuts
-    width/heads/vocab/experts to toy sizes.
+    width/heads/vocab/experts to toy sizes.  A KV-sharing stack keeps every
+    run with at most two repeats, and a few meta tokens.
     """
     pattern = cfg.layer_pattern
     n_layers = min(cfg.n_layers, len(pattern) + 1)  # 1 superblock + 1 rem
+    runs = cfg.stack_runs()
+    if runs:
+        pattern = tuple(t for g, n in runs for t in g * min(n, 2))
+        n_layers = len(pattern)
     moe = None
     if cfg.moe is not None:
         moe = dataclasses.replace(
@@ -163,7 +244,7 @@ def reduced(cfg: ArchConfig) -> ArchConfig:
             top_k=min(2, cfg.moe.top_k), router_group=64, expert_d_ff=64)
     ssm = cfg.ssm
     if ssm is not None:
-        ssm = dataclasses.replace(ssm, state_dim=4)
+        ssm = dataclasses.replace(ssm, state_dim=4, dt_rank=0)
     xl = cfg.xlstm
     if xl is not None:
         xl = dataclasses.replace(xl, chunk=16, slstm_every=2)
@@ -174,10 +255,12 @@ def reduced(cfg: ArchConfig) -> ArchConfig:
     return dataclasses.replace(
         cfg,
         n_layers=n_layers,
+        layer_pattern=pattern,
         d_model=64,
         n_heads=n_heads,
         n_kv_heads=n_kv,
         head_dim=16,
+        meta_tokens=min(cfg.meta_tokens, 4),
         d_ff=0 if cfg.d_ff == 0 else 128,
         vocab=256,
         window=8,

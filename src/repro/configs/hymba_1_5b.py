@@ -1,16 +1,30 @@
-"""hymba-1.5b [hybrid] — parallel attention + mamba heads per layer.
+"""hymba-1.5b [hybrid] — parallel attention + mamba heads in every layer.
 
-32L d_model=1600 25H (GQA kv=5) d_ff=5504 vocab=32001, ssm_state=16
-[arXiv:2411.13676; hf].  Hymba uses sliding-window attention on most layers
-with a few full-attention layers (first/middle/last per the paper); the
-mamba heads run in parallel with the attention heads inside every layer.
-Sub-quadratic ⇒ the long_500k cell runs for this arch.
+Sizes from the published ``config.json`` of nvidia/Hymba-1.5B-Base and
+arXiv:2411.13676: 32 layers, d_model 1600, 25 query heads over 5 KV heads,
+q/k head size 64 and value head size 128 (so the attention heads' output,
+25 x 128, matches the SSM's d_inner 3200), SwiGLU d_ff 5504, vocab 32001,
+tied embeddings, RMSNorm eps 1e-6, RoPE theta 10000.  The SSM heads are
+Mamba-1 style: expand 2, state 16, a width-4 convolution with bias,
+dt_rank 100, RMSNorms on dt, B and C.  Full attention at layers 0, 15 and
+31 (``global_attn_idx``), a 1024-token sliding window elsewhere; the KV
+cache is shared within ``kv_reuse_group`` (1,2), (3,4), ... (13,14),
+(16,17,18), (19,20), ... (29,30), whose later members project only Q;
+128 meta tokens are prepended to every sequence.
 """
-from repro.configs.base import ATTN, HYBRID, ArchConfig, SSMConfig
+from repro.configs.base import HYBRID, SHARED_KV, ArchConfig, SSMConfig
 
-# Pattern of 8 positions tiled 4x over 32 layers: position 0 is a
-# full-attention hybrid layer, positions 1..7 use sliding-window attention
-# in the attention half of the hybrid head group.
+FULL = HYBRID + ":full"
+LOCAL = HYBRID + ":local"
+REUSE = LOCAL + SHARED_KV
+
+
+def _pattern():
+    pairs = (LOCAL, REUSE) * 7
+    return ((FULL,) + pairs + (FULL, LOCAL, REUSE, REUSE) + (LOCAL, REUSE) * 6
+            + (FULL,))
+
+
 CONFIG = ArchConfig(
     name="hymba-1.5b",
     family="hybrid",
@@ -21,8 +35,12 @@ CONFIG = ArchConfig(
     d_ff=5504,
     vocab=32001,
     head_dim=64,
-    layer_pattern=(HYBRID + ":full",) + (HYBRID + ":local",) * 7,
+    v_head_mult=2,
+    layer_pattern=_pattern(),
     window=1024,
-    ssm=SSMConfig(state_dim=16, conv_width=4, expand=1),
+    meta_tokens=128,
+    ssm=SSMConfig(state_dim=16, conv_width=4, expand=2, dt_rank=100),
     rope_theta=10000.0,
+    norm_eps=1e-6,
+    tie_embeddings=True,
 )

@@ -136,9 +136,9 @@ class AttnParams:
     """Weight bundle for one attention mixer (arrays may be batched by a
     leading superblock dim before being sliced by scan)."""
     wq: Array        # (d, H, Dh)
-    wk: Array        # (d, K, Dh)
-    wv: Array        # (d, K, Dh)
-    wo: Array        # (H, Dh, d)
+    wk: Optional[Array]   # (d, K, Dh); None: the layer projects only Q
+    wv: Optional[Array]   # (d, K, Dv)
+    wo: Array        # (H, Dv, d)
     q_norm: Optional[Array] = None   # (Dh,) gemma3 qk-norm
     k_norm: Optional[Array] = None
 
@@ -146,21 +146,25 @@ class AttnParams:
 def project_qkv(x: Array, p: AttnParams, n_kv: int, *, positions: Array,
                 theta: float, qk_norm_eps: float = 1e-6,
                 use_rope: bool = True) -> Tuple[Array, Array, Array]:
-    """x: (B, S, d) -> q: (B, S, K, G, Dh); k, v: (B, S, K, Dh)."""
+    """x: (B, S, d) -> q: (B, S, K, G, Dh); k: (B, S, K, Dh), v: (B, S, K,
+    Dv), or None for both when ``p.wk`` is None."""
     dt = x.dtype
     pref = _row_reduce_dtype(dt)
     q = jnp.einsum("bsd,dhe->bshe", x, p.wq.astype(dt),
                    preferred_element_type=pref).astype(dt)
-    k = jnp.einsum("bsd,dke->bske", x, p.wk.astype(dt),
-                   preferred_element_type=pref).astype(dt)
-    v = jnp.einsum("bsd,dke->bske", x, p.wv.astype(dt),
-                   preferred_element_type=pref).astype(dt)
+    k = v = None
+    if p.wk is not None:
+        k = jnp.einsum("bsd,dke->bske", x, p.wk.astype(dt),
+                       preferred_element_type=pref).astype(dt)
+        v = jnp.einsum("bsd,dke->bske", x, p.wv.astype(dt),
+                       preferred_element_type=pref).astype(dt)
     if p.q_norm is not None:
         q = rms_norm(q, p.q_norm, qk_norm_eps)
         k = rms_norm(k, p.k_norm, qk_norm_eps)
     if use_rope:
         q = apply_rope(q, positions, theta)
-        k = apply_rope(k, positions, theta)
+        if k is not None:
+            k = apply_rope(k, positions, theta)
     b, s, h, e = q.shape
     g = h // n_kv
     q = q.reshape(b, s, n_kv, g, e)
@@ -200,64 +204,77 @@ def _finish(m, l, acc, dtype):
 def blockwise_attention(q: Array, k: Array, v: Array, *, causal: bool,
                         window: int = 0, softcap: float = 0.0,
                         q_block: int = 1024, kv_block: int = 1024,
-                        scale: Optional[float] = None) -> Array:
+                        scale: Optional[float] = None,
+                        n_meta: int = 0) -> Array:
     """Streaming-softmax attention.
 
-    q: (B, S, K, G, Dh); k, v: (B, Skv, K, Dh).  Returns (B, S, K, G, Dh).
+    q: (B, S, K, G, Dh); k: (B, Skv, K, Dh); v: (B, Skv, K, Dv).  Returns
+    (B, S, K, G, Dv).
 
-    causal=True  -> static triangular schedule over query blocks.
+    causal=True  -> static triangular schedule over query blocks (the last
+                    one may be shorter).
     window>0     -> additionally banded: query block i only reads the KV
                     slice [i*qb - window, (i+1)*qb)  (static slice).
+    n_meta>0     -> the first n_meta keys are seen by every query despite
+                    the window (hymba's meta tokens): a block whose band
+                    starts past them reads them first.
     causal=False -> full bidirectional / cross attention.
+    A KV span longer than kv_block that it does not divide is read as its
+    whole blocks, then the rest.
     """
     b, s, n_kv, g, dh = q.shape
+    dv = v.shape[-1]
     skv = k.shape[1]
     scale = scale if scale is not None else 1.0 / math.sqrt(dh)
     q = (q * scale).astype(q.dtype)
 
     qb = min(q_block, s)
-    if s % qb:
-        qb = s  # tiny/odd sequences: single block
-    n_qb = s // qb
     # (B, K, G, S, Dh) layout for the inner loops.
     qt = q.transpose(0, 2, 3, 1, 4)
 
-    out_blocks = []
-    for i in range(n_qb):
-        q_i = lax.slice_in_dim(qt, i * qb, (i + 1) * qb, axis=3)
-        q_pos0 = i * qb
-        if causal:
-            lo = max(0, q_pos0 - window + 1) if window else 0
-            lo = (lo // kv_block) * kv_block
-            hi = min(skv, (i + 1) * qb)
-        else:
-            lo, hi = 0, skv
+    def attend(carry, q_i, q_pos0, lo, hi):
+        """Fold keys [lo, hi) into the online-softmax carry (a fresh one
+        when ``carry`` is None)."""
+        nq = q_i.shape[3]
         k_i = lax.slice_in_dim(k, lo, hi, axis=1)
         v_i = lax.slice_in_dim(v, lo, hi, axis=1)
+        if carry is None:
+            carry = (jnp.full((b, n_kv, g, nq), NEG_INF, jnp.float32),
+                     jnp.zeros((b, n_kv, g, nq), jnp.float32),
+                     jnp.zeros((b, n_kv, g, nq, dv), jnp.float32))
+
+        def mask_of(k0, nk):
+            """Mask of the queries over keys k0 .. k0 + nk - 1."""
+            if not (causal or window):
+                return None
+            qpos = q_pos0 + jnp.arange(nq)
+            kpos = k0 + jnp.arange(nk)
+            m = jnp.ones((nq, nk), bool)
+            if causal:
+                m &= qpos[:, None] >= kpos[None, :]
+            if window:
+                near = qpos[:, None] - kpos[None, :] < window
+                if n_meta:
+                    near |= kpos[None, :] < n_meta
+                m &= near
+            return m
+
         span = hi - lo
         kb = min(kv_block, span)
-        m0 = jnp.full((b, n_kv, g, qb), NEG_INF, jnp.float32)
-        l0 = jnp.zeros((b, n_kv, g, qb), jnp.float32)
-        a0 = jnp.zeros((b, n_kv, g, qb, dh), jnp.float32)
-        if span % kb == 0 and span // kb > 1:
-            n_kb = span // kb
-            ks = k_i.reshape(b, n_kb, kb, n_kv, dh).transpose(1, 0, 2, 3, 4)
-            vs = v_i.reshape(b, n_kb, kb, n_kv, dh).transpose(1, 0, 2, 3, 4)
+        n_kb, tail = divmod(span, kb)
+        done = 0
+        if n_kb > 1:
+            whole = n_kb * kb
+            ks, vs = ((t if not tail else lax.slice_in_dim(t, 0, whole, axis=1))
+                      for t in (k_i, v_i))
+            ks = ks.reshape(b, n_kb, kb, n_kv, dh).transpose(1, 0, 2, 3, 4)
+            vs = vs.reshape(b, n_kb, kb, n_kv, dv).transpose(1, 0, 2, 3, 4)
             jidx = jnp.arange(n_kb)
 
             def body(carry, xs):
                 k_blk, v_blk, j = xs
-                qpos = q_pos0 + jnp.arange(qb)
-                kpos = lo + j * kb + jnp.arange(kb)
-                mask = None
-                if causal or window:
-                    m = jnp.ones((qb, kb), bool)
-                    if causal:
-                        m &= qpos[:, None] >= kpos[None, :]
-                    if window:
-                        m &= qpos[:, None] - kpos[None, :] < window
-                    mask = m
-                return _online_softmax_block(carry, q_i, k_blk, v_blk, mask,
+                return _online_softmax_block(carry, q_i, k_blk, v_blk,
+                                             mask_of(lo + j * kb, kb),
                                              softcap), None
 
             # Flash-attention-style backward: remat the KV-block body so the
@@ -266,34 +283,52 @@ def blockwise_attention(q: Array, k: Array, v: Array, *, causal: bool,
             # otherwise) — backward recomputes them from the saved k/v
             # blocks.
             body = jax.checkpoint(body)
-            (m, l, acc), _ = lax.scan(body, (m0, l0, a0), (ks, vs, jidx))
+            carry, _ = lax.scan(body, carry, (ks, vs, jidx))
+            done = whole
+        for a, z in ((done, span - tail), (span - tail, span)):
+            if z > a:
+                kk, vv = ((t if (a, z) == (0, span)
+                           else lax.slice_in_dim(t, a, z, axis=1))
+                          for t in (k_i, v_i))
+                carry = _online_softmax_block(carry, q_i, kk, vv,
+                                              mask_of(lo + a, z - a),
+                                              softcap)
+        return carry
+
+    out_blocks = []
+    for q_pos0 in range(0, s, qb):
+        q_i = lax.slice_in_dim(qt, q_pos0, min(q_pos0 + qb, s), axis=3)
+        nq = q_i.shape[3]
+        if causal:
+            lo = max(0, q_pos0 - window + 1) if window else 0
+            lo = (lo // kv_block) * kv_block
+            hi = min(skv, q_pos0 + nq)
         else:
-            qpos = q_pos0 + jnp.arange(qb)
-            kpos = lo + jnp.arange(span)
-            mask = None
-            if causal or window:
-                mm = jnp.ones((qb, span), bool)
-                if causal:
-                    mm &= qpos[:, None] >= kpos[None, :]
-                if window:
-                    mm &= qpos[:, None] - kpos[None, :] < window
-                mask = mm
-            m, l, acc = _online_softmax_block((m0, l0, a0), q_i, k_i, v_i,
-                                              mask, softcap)
-        out_blocks.append(_finish(m, l, acc, q.dtype))
-    out = jnp.concatenate(out_blocks, axis=3) if n_qb > 1 else out_blocks[0]
-    return out.transpose(0, 3, 1, 2, 4)  # (B, S, K, G, Dh)
+            lo, hi = 0, skv
+        carry = None
+        if n_meta and lo > 0:
+            if lo <= n_meta:
+                lo = 0
+            else:
+                carry = attend(carry, q_i, q_pos0, 0, n_meta)
+        carry = attend(carry, q_i, q_pos0, lo, hi)
+        out_blocks.append(_finish(*carry, q.dtype))
+    out = (jnp.concatenate(out_blocks, axis=3) if len(out_blocks) > 1
+           else out_blocks[0])
+    return out.transpose(0, 3, 1, 2, 4)  # (B, S, K, G, Dv)
 
 
 def decode_attention(q: Array, k_cache: Array, v_cache: Array,
                      kv_positions: Array, pos: Array, *, window: int = 0,
                      softcap: float = 0.0,
-                     scale: Optional[float] = None) -> Array:
+                     scale: Optional[float] = None,
+                     n_meta: int = 0) -> Array:
     """Single-step attention against a cache.
 
-    q: (B, 1, K, G, Dh); k_cache/v_cache: (B, C, K, Dh);
+    q: (B, 1, K, G, Dh); k_cache: (B, C, K, Dh); v_cache: (B, C, K, Dv);
     kv_positions: (C,) absolute position held by each cache slot (−1 empty);
-    pos: scalar current position.  Window masking uses absolute positions.
+    pos: scalar current position.  Window masking uses absolute positions;
+    the first ``n_meta`` positions are inside every window.
     """
     b, _, n_kv, g, dh = q.shape
     scale = scale if scale is not None else 1.0 / math.sqrt(dh)
@@ -303,7 +338,10 @@ def decode_attention(q: Array, k_cache: Array, v_cache: Array,
     s = _softcap(s, softcap)
     valid = (kv_positions >= 0) & (kv_positions <= pos)
     if window:
-        valid &= kv_positions > pos - window
+        near = kv_positions > pos - window
+        if n_meta:
+            near |= kv_positions < n_meta
+        valid &= near
     s = jnp.where(valid[None, None, None, :], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bkgj,bjkd->bkgd", p.astype(v_cache.dtype), v_cache,
@@ -325,17 +363,29 @@ def attn_out(attended: Array, wo: Array) -> Array:
 # ---------------------------------------------------------------------------
 
 
-def ring_slot_positions(pos: Array, cap: int) -> Array:
+def ring_slot_positions(pos: Array, cap: int, n_meta: int = 0) -> Array:
     """Absolute position stored in each ring slot after writing `pos` at
     slot pos % cap.  Slot w holds the largest p <= pos with p % cap == w
-    (or -1 if none)."""
+    (or -1 if none).  With ``n_meta`` pinned slots, slot w < n_meta holds
+    position w and the rest are a ring of cap - n_meta over the positions
+    from n_meta on."""
+    if n_meta:
+        ring = ring_slot_positions(pos - n_meta, cap - n_meta)
+        return jnp.concatenate([jnp.arange(n_meta),
+                                jnp.where(ring >= 0, ring + n_meta, -1)])
     slots = jnp.arange(cap)
     p = pos - ((pos - slots) % cap)
     return jnp.where(p >= 0, p, -1)
 
 
-def ring_write(cache: Array, value: Array, pos: Array, cap: int) -> Array:
-    """cache: (B, cap, ...); value: (B, 1, ...) written at slot pos % cap."""
-    slot = (pos % cap).astype(jnp.int32)
+def ring_write(cache: Array, value: Array, pos: Array, cap: int,
+               n_meta: int = 0) -> Array:
+    """cache: (B, cap, ...); value: (B, 1, ...) written at slot pos % cap,
+    or past ``n_meta`` pinned slots at n_meta + (pos - n_meta) % (cap -
+    n_meta) (``pos`` >= n_meta)."""
+    if n_meta:
+        slot = (n_meta + (pos - n_meta) % (cap - n_meta)).astype(jnp.int32)
+    else:
+        slot = (pos % cap).astype(jnp.int32)
     return lax.dynamic_update_slice_in_dim(cache, value.astype(cache.dtype),
                                            slot, axis=1)

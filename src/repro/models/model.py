@@ -142,62 +142,36 @@ class Model:
                pos: Optional[Array] = None,
                s_max: Optional[int] = None) -> Tuple[Array, Optional[PyTree]]:
         cfg = self.cfg
-        pattern, n_super, rem = cfg.pattern_plan()
         new_cache: Dict[str, Any] = {}
-
-        if n_super:
-            if mode == "train":
-                def body(carry, bp):
-                    y = carry
-                    for i, tag in enumerate(pattern):
-                        y, _ = B.apply_layer(cfg, tag, bp[f"pos{i}"], y,
-                                             mode="train", ctx=ctx)
-                    return y, None
+        for keys, period, n in B.segments(cfg):
+            bp = B.subtree(params, keys)
+            bc = B.subtree(cache, keys) if mode == "decode" else None
+            kw = dict(mode=mode, ctx=ctx, pos=pos, s_max=s_max)
+            if n:
+                def body(carry, xs, period=period):
+                    y, caches = B.apply_period(cfg, period, xs[0], carry,
+                                               caches=xs[1], **kw)
+                    return y, (None if mode == "train" else caches)
                 body = self._maybe_remat_scan_body(body, mode)
-                x, _ = lax.scan(body, x, params["blocks"])
-            elif mode == "prefill":
-                def body(carry, bp):
-                    y = carry
-                    caches = {}
-                    for i, tag in enumerate(pattern):
-                        y, c = B.apply_layer(cfg, tag, bp[f"pos{i}"], y,
-                                             mode="prefill", ctx=ctx,
-                                             s_max=s_max)
-                        caches[f"pos{i}"] = c
-                    return y, caches
-                x, blk_caches = lax.scan(body, x, params["blocks"])
-                new_cache["blocks"] = blk_caches
-            else:  # decode
-                def body(carry, xs):
-                    bp, bc = xs
-                    y = carry
-                    caches = {}
-                    for i, tag in enumerate(pattern):
-                        y, c = B.apply_layer(cfg, tag, bp[f"pos{i}"], y,
-                                             mode="decode",
-                                             cache=bc[f"pos{i}"], pos=pos)
-                        caches[f"pos{i}"] = c
-                    return y, caches
-                x, blk_caches = lax.scan(body, x,
-                                         (params["blocks"], cache["blocks"]))
-                new_cache["blocks"] = blk_caches
-
-        if rem:
-            rem_caches = {}
-            for i, tag in enumerate(rem):
-                rp = params["rem"][f"rem{i}"]
-                if mode == "decode":
-                    x, c = B.apply_layer(cfg, tag, rp, x, mode="decode",
-                                         cache=cache["rem"][f"rem{i}"],
-                                         pos=pos)
-                else:
-                    x, c = B.apply_layer(cfg, tag, rp, x, mode=mode, ctx=ctx,
-                                         s_max=s_max)
-                rem_caches[f"rem{i}"] = c
-            if mode == "prefill" or mode == "decode":
-                new_cache["rem"] = rem_caches
-
+                x, c = lax.scan(body, x, (bp, bc))
+            else:
+                x, c = B.apply_period(
+                    cfg, period, {"pos0": bp}, x,
+                    caches=None if bc is None else {"pos0": bc}, **kw)
+                c = c["pos0"]
+            if mode != "train":
+                B.put(new_cache, keys, c)
         return x, (new_cache if new_cache else None)
+
+    def _with_meta(self, params: PyTree, x: Array) -> Array:
+        """The meta tokens' rows before every sequence of ``x`` (B, S, d)."""
+        m = self.cfg.meta_tokens
+        if not m:
+            return x
+        meta = params["meta"].astype(x.dtype)
+        return jnp.concatenate(
+            [jnp.broadcast_to(meta[None], (x.shape[0],) + meta.shape), x],
+            axis=1)
 
     def _head(self, params: PyTree, x: Array) -> Array:
         """x: (..., d) -> logits (..., Vp) f32."""
@@ -219,9 +193,10 @@ class Model:
         cfg = self.cfg
         tokens, labels = batch["tokens"], batch["labels"]
         ctx = self._context(params, batch, "train")
-        x = self._embed(params, tokens)
+        x = self._with_meta(params, self._embed(params, tokens))
         x, _ = self._stack(params, x, ctx, "train")
-        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        x = rms_norm(x[:, cfg.meta_tokens:], params["final_norm"],
+                     cfg.norm_eps)
 
         b, s, d = x.shape
         chunk = min(self.loss_chunk, s)
@@ -256,22 +231,27 @@ class Model:
 
     def prefill(self, params: PyTree, batch: Dict[str, Array],
                 s_max: Optional[int] = None) -> Tuple[Array, PyTree]:
-        """s_max: decode-cache capacity to allocate (>= tokens.shape[1];
-        defaults to the prompt length)."""
+        """s_max: decode-cache capacity to allocate, in tokens (>=
+        tokens.shape[1]; defaults to the prompt length).  The meta tokens
+        come first; an empty prompt prefills them alone."""
         cfg = self.cfg
         tokens = batch["tokens"]
         ctx = self._context(params, batch, "prefill")
-        x = self._embed(params, tokens)
-        x, kv = self._stack(params, x, ctx, "prefill", s_max=s_max)
+        x = self._with_meta(params, self._embed(params, tokens))
+        x, kv = self._stack(params, x, ctx, "prefill",
+                            s_max=s_max or tokens.shape[1])
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
         logits = self._head(params, x[:, -1])
         return _mask_padded_vocab(logits, cfg.vocab), kv
 
     def decode_step(self, params: PyTree, cache: PyTree, tokens: Array,
                     pos: Array) -> Tuple[Array, PyTree]:
-        """tokens: (B,) int32; pos: scalar int32 (position being written)."""
+        """tokens: (B,) int32; pos: scalar int32 (position being written,
+        counted in tokens: the meta tokens sit before position 0)."""
         cfg = self.cfg
         x = self._embed(params, tokens[:, None])
+        if cfg.meta_tokens:
+            pos = pos + cfg.meta_tokens
         x, kv = self._stack(params, x, None, "decode", cache=cache, pos=pos)
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
         logits = self._head(params, x[:, 0])

@@ -29,18 +29,17 @@ def _ssm_combine(e1, e2):
     return a1 * a2, a2 * b1 + b2
 
 
-def depthwise_conv(x: Array, w: Array, tail: Array | None = None) -> Tuple[Array, Array]:
-    """Causal depthwise conv1d.
+def depthwise_conv(x: Array, w: Array, bias: Array) -> Tuple[Array, Array]:
+    """Causal depthwise conv1d with a bias, over a fresh sequence.
 
-    x: (B, S, C); w: (C, K).  tail: (B, K-1, C) state from previous segment
-    (zeros for a fresh sequence).  Returns (y, new_tail).
+    x: (B, S, C); w: (C, K); bias: (C,).  Returns (y, the last K-1 inputs:
+    the decode tail).
     """
     b, s, c = x.shape
     k = w.shape[1]
-    if tail is None:
-        tail = jnp.zeros((b, k - 1, c), x.dtype)
+    tail = jnp.zeros((b, k - 1, c), x.dtype)
     xp = jnp.concatenate([tail, x], axis=1)            # (B, S+K-1, C)
-    y = jnp.zeros((b, s, c), jnp.float32)
+    y = jnp.broadcast_to(bias.astype(jnp.float32), (b, s, c))
     for i in range(k):
         y = y + xp[:, i:i + s].astype(jnp.float32) * w[:, i].astype(jnp.float32)
     new_tail = xp[:, s:]                                # last K-1 inputs
@@ -60,13 +59,19 @@ def ssm_scan(x_in: Array, dt: Array, a_log: Array, bmat: Array, cmat: Array,
     d_skip:(C,)        skip connection
     state0:(B, C, N)   initial state
     Returns (y (B, S, C) f32->x dtype, final_state (B, C, N) f32).
+
+    A sequence longer than ``chunk`` that it does not divide is padded at
+    the end with steps of dt = 0, which leave the state as it was.
     """
     b, s, c = x_in.shape
     n = a_log.shape[1]
     ch = min(chunk, s)
-    if s % ch:
-        ch = s
-    n_chunks = s // ch
+    pad = -s % ch
+    if pad:
+        x_in, dt, bmat, cmat = (
+            jnp.pad(t, ((0, 0), (0, pad), (0, 0)))
+            for t in (x_in, dt, bmat, cmat))
+    n_chunks = (s + pad) // ch
 
     a = -jnp.exp(a_log.astype(jnp.float32))            # (C, N), negative
 
@@ -93,7 +98,7 @@ def ssm_scan(x_in: Array, dt: Array, a_log: Array, bmat: Array, cmat: Array,
         # blockwise_attention / mlstm_chunkwise).
         state_f, ys = lax.scan(jax.checkpoint(per_chunk),
                                state0.astype(jnp.float32), xs)
-        y = ys.swapaxes(0, 1).reshape(b, s, c)
+        y = ys.swapaxes(0, 1).reshape(b, s + pad, c)[:, :s]
     else:
         state_f, y = per_chunk(state0.astype(jnp.float32), (x_in, dt, bmat, cmat))
     return y.astype(x_in.dtype), state_f

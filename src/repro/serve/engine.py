@@ -226,10 +226,11 @@ class ServingEngine:
         (g, ...) cache rows into their slots with one indexed device
         update per cache leaf — the grouped re-prefill unit of the
         batched recovery path.  An empty prefix (one-token log) seats the
-        zero state every sequence starts from."""
+        zero state every sequence starts from; with meta tokens, it
+        prefills them alone."""
         g = len(slots)
         idx = jnp.asarray(slots, jnp.int32)
-        if tokens.shape[1] == 0:
+        if tokens.shape[1] == 0 and not self.model.cfg.meta_tokens:
             kv = self.model.init_cache(g, self.cfg.s_max)
             with self._cache_lock:
                 self.cache = _map_slot(
@@ -454,7 +455,11 @@ def _reconstruct_engine(eng: "ServingEngine") -> dict:
     The stage's seconds end at dispatch: nothing here waits for the
     prefills and scatters to finish on the device (the first ``step()``
     does).  Their device time is ``recovery.rebuild_device_ms`` in the
-    benchmark, read from a trace after the ``recover.engine`` span."""
+    benchmark, read from a trace after the ``recover.engine`` span.  Each
+    group's prefill and scatter is a ``recover.prefill_group`` span; the
+    details count the positions re-prefilled, meta tokens included
+    (``rebuild_positions``), and each group's ``[tokens, sequences]``
+    (``rebuild_groups``)."""
     cfg = eng.cfg
     t0 = time.perf_counter()
     eng.cache = eng.model.init_cache(cfg.max_batch, cfg.s_max)
@@ -517,8 +522,9 @@ def _reconstruct_engine(eng: "ServingEngine") -> dict:
     def prefill_group(key: Tuple[int, int]) -> float:
         shard, tl = key
         sel = slots[(shards == shard) & (tlens == tl)]
-        eng._prefill_slots(sel, np.asarray(
-            eng.tok_region.read_at(sel, slice(0, tl - 1)), np.int32))
+        with obs.span("recover.prefill_group"):
+            eng._prefill_slots(sel, np.asarray(
+                eng.tok_region.read_at(sel, slice(0, tl - 1)), np.int32))
         with eng._admit_lock:
             eng.slot_ready[sel] = True
             admitted = time.perf_counter() - t0
@@ -534,8 +540,14 @@ def _reconstruct_engine(eng: "ServingEngine") -> dict:
             admissions = list(ex.map(prefill_group, groups))
     else:
         admissions = [prefill_group(g) for g in groups]
+    sizes = [[tl - 1, int(((shards == sh) & (tlens == tl)).sum())]
+             for sh, tl in groups]
+    meta = eng.model.cfg.meta_tokens
     out = {"requests": int(live.sum()),
            "prefill_groups": len(groups),
+           "rebuild_groups": sizes,
+           "rebuild_positions": sum(n * (t + meta) for t, n in sizes
+                                    if t or meta),
            "shard_groups": int(np.unique(shards).size) if slots.size
            else 0,
            "first_admission_s": round(min(admissions), 6)

@@ -29,10 +29,10 @@ def test_refuses_without_tpu(smoke, capsys):
 
 
 def test_serve_crash_recover_matches_twin(smoke, tmp_path):
-    params = smoke.serve_and_recover(
+    model, params = smoke.serve_and_recover(
         full_size=False, seed=0, workdir=tmp_path,
         compiles=smoke.CompileLog(), prompt_lens=(12, 20, 12, 20), s_max=64)
-    assert params["blocks"]["pos0"]["mlp"]["w_up"].ndim == 3
+    assert smoke.ffn_leaf(model.cfg, params).shape == (64, 128)
 
 
 def test_compare_to_twin_rejects_a_shifted_state(smoke):

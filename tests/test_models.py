@@ -12,8 +12,9 @@ from repro.models import moe as M
 KEY = jax.random.PRNGKey(0)
 
 
-def naive_attention(q, k, v, causal=True, window=0, softcap=0.0):
-    """O(S^2) reference: q (B,S,K,G,D); k,v (B,S,K,D)."""
+def naive_attention(q, k, v, causal=True, window=0, softcap=0.0, n_meta=0):
+    """O(S^2) reference: q (B,S,K,G,D); k,v (B,S,K,D); the first n_meta
+    keys are inside every window."""
     b, s, nk, g, d = q.shape
     qf = q.astype(jnp.float32) / jnp.sqrt(d)
     s_ = jnp.einsum("bqkgd,bjkd->bkgqj", qf, k.astype(jnp.float32))
@@ -25,7 +26,7 @@ def naive_attention(q, k, v, causal=True, window=0, softcap=0.0):
     if causal:
         mask &= qpos >= kpos
     if window:
-        mask &= qpos - kpos < window
+        mask &= (qpos - kpos < window) | (kpos < n_meta)
     s_ = jnp.where(mask[None, None, None], s_, -1e30)
     p = jax.nn.softmax(s_, -1)
     out = jnp.einsum("bkgqj,bjkd->bkgqd", p, v.astype(jnp.float32))
@@ -58,6 +59,73 @@ def test_sliding_window_matches_naive(window):
     want = naive_attention(q, k, v, causal=True, window=window)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("s,window,n_meta", [(64, 8, 4), (60, 8, 4),
+                                             (60, 20, 24), (45, 0, 0)])
+def test_meta_window_and_ragged_blocks_match_naive(s, window, n_meta):
+    """Meta keys inside every window (a band past them reads them first),
+    a ragged last query block and ragged KV spans, and value heads wider
+    than q/k heads."""
+    q, k, _ = rand_qkv(s=s)
+    v = jax.random.normal(KEY, (2, s, 2, 32))
+    got = L.blockwise_attention(q, k, v, causal=True, window=window,
+                                q_block=16, kv_block=16, n_meta=n_meta)
+    want = naive_attention(q, k, v, causal=True, window=window,
+                           n_meta=n_meta)
+    assert got.shape == (2, s, 2, 2, 32)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-5, rtol=2e-5)
+
+
+def test_decode_attention_over_a_ring_with_meta_slots():
+    """A ring of 8 behind 4 pinned meta slots, written position by
+    position past its wrap, attends as the last row of windowed attention
+    with the meta tokens always seen."""
+    s, m, w = 30, 4, 8
+    q, k, v = rand_qkv(s=s)
+    want = naive_attention(q, k, v, causal=True, window=w, n_meta=m)
+    kc = jnp.zeros((2, m + w, 2, 16))
+    vc = jnp.zeros((2, m + w, 2, 16))
+    for p in range(s):
+        pos = jnp.asarray(p)
+        if p < m:
+            kc, vc = kc.at[:, p].set(k[:, p]), vc.at[:, p].set(v[:, p])
+        else:
+            kc = L.ring_write(kc, k[:, p:p + 1], pos, m + w, m)
+            vc = L.ring_write(vc, v[:, p:p + 1], pos, m + w, m)
+        if p < m + w:
+            continue
+        kv_pos = L.ring_slot_positions(pos, m + w, m)
+        assert sorted(np.asarray(kv_pos).tolist()) == \
+            list(range(m)) + list(range(p - w + 1, p + 1))
+        got = L.decode_attention(q[:, p:p + 1], kc, vc, kv_pos, pos,
+                                 window=w, n_meta=m)
+        np.testing.assert_allclose(np.asarray(got[:, 0]),
+                                   np.asarray(want[:, p]),
+                                   atol=2e-5, rtol=2e-5)
+
+
+def test_ssm_scan_pads_a_ragged_last_chunk():
+    """A sequence the chunk does not divide gives the one-chunk scan's
+    outputs and final state (the padded steps leave the state as it
+    was)."""
+    from repro.models import ssm as S
+
+    b, s, c, n = 2, 50, 8, 4
+    ks = jax.random.split(KEY, 6)
+    x = jax.random.normal(ks[0], (b, s, c))
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (b, s, c)))
+    a_log = jax.random.normal(ks[2], (c, n))
+    bm, cm = (jax.random.normal(k, (b, s, n)) for k in ks[3:5])
+    h0 = jax.random.normal(ks[5], (b, c, n))
+    d = jnp.ones((c,))
+    y1, h1 = S.ssm_scan(x, dt, a_log, bm, cm, d, h0, chunk=16)
+    y2, h2 = S.ssm_scan(x, dt, a_log, bm, cm, d, h0, chunk=s)
+    np.testing.assert_allclose(np.asarray(y1), np.asarray(y2), rtol=1e-5,
+                               atol=1e-5)
+    np.testing.assert_allclose(np.asarray(h1), np.asarray(h2), rtol=1e-5,
+                               atol=1e-5)
 
 
 def test_softcap_matches_naive():

@@ -104,7 +104,8 @@ def test_recover_records_one_span_per_report_stage(model_params, tmp_path,
         eng.recover(concurrency=concurrency)
     rep = eng.last_recovery
     assert rep.stage("engine").detail["prefill_groups"] == 2
-    spans = [s for s in obs.recorded() if s.name.startswith("recover.")]
+    spans = [s for s in obs.recorded() if s.name.startswith("recover.")
+             and s.name != "recover.prefill_group"]
     assert sorted(s.name for s in spans) == \
         sorted("recover." + st.name for st in rep.stages)
     for s in spans:
@@ -142,3 +143,39 @@ def test_every_name_has_a_program_prefix_and_reaches_the_trace(
               if plane.name.startswith("/host:")
               for line in plane.lines for ev in line.events}
     assert names <= events
+
+
+def test_each_prefill_group_is_a_span_under_the_engine_stage(model_params,
+                                                             tmp_path):
+    eng = _engine(model_params, tmp_path, 3)
+    eng.step()
+    eng.crash()
+    obs.clear()
+    with _profiling(tmp_path):
+        eng.recover()
+    detail = eng.last_recovery.stage("engine").detail
+    spans = obs.recorded()
+    (stage,) = [s for s in spans if s.name == "recover.engine"]
+    groups = [s for s in spans if s.name == "recover.prefill_group"]
+    assert len(groups) == detail["prefill_groups"] == 2
+    for g in groups:
+        assert g.parent == "recover.engine" and g.thread == stage.thread
+        assert stage.t0 <= g.t0 <= g.t1 <= stage.t1
+
+
+@pytest.mark.parametrize("arch", ["llama3.2-3b", "hymba-1.5b"])
+def test_rebuild_positions_count_every_group_and_the_meta_tokens(tmp_path,
+                                                                 arch):
+    cfg = base.reduced(registry.get(arch))
+    model = build(cfg, compute_dtype=jnp.float32)
+    eng = _engine((model, model.init_params(jax.random.PRNGKey(0))),
+                  tmp_path, 3)
+    eng.step()
+    eng.crash()
+    eng.recover()
+    detail = eng.last_recovery.stage("engine").detail
+    # logs of 4, 6, 4 tokens: prefixes of 3 and 5 re-prefilled
+    assert sorted(detail["rebuild_groups"]) == [[3, 2], [5, 1]]
+    m = cfg.meta_tokens
+    assert (m > 0) == (arch == "hymba-1.5b")
+    assert detail["rebuild_positions"] == 2 * (3 + m) + (5 + m)
