@@ -11,8 +11,9 @@ Weights are not session state: the engine serves a compute-dtype copy of
 the matmul weights (``Model.compute_params``), made once at construction,
 and a crash keeps it.
 
-The decode path runs a jit'd `decode_step` over fixed batch slots
-(slot-contiguous caches; the paged allocator manages page *metadata* —
+The decode path runs one jit'd program per slot: the slot's cache rows
+out at a traced index, a B=1 `decode_step`, the rows back in (fixed batch
+slots, slot-contiguous caches; the paged allocator manages page *metadata* —
 documented simplification, DESIGN.md §3).  A slot's device state is the
 model state after every logged token but the last: the next step feeds
 that last token at its own position, so admission and recovery both
@@ -162,7 +163,24 @@ class ServingEngine:
         # distinct from _cache_lock so a callback may decode (step())
         self._admit_lock = threading.Lock()
         self._recover_concurrency = 1
-        self._decode = jax.jit(model.decode_step)
+
+        def slot_decode_step(params, cache, slot, token, pos):
+            # one program a slot: the slot's rows out of the shared tree
+            # at a traced index, the B=1 decode, the rows written back
+            one = _map_slot(
+                cache, cache,
+                lambda full, _, ax: jax.lax.dynamic_slice_in_dim(
+                    full, slot, 1, axis=ax))
+            logits, one = model.decode_step(
+                params, one, jnp.full((1,), token, jnp.int32),
+                jnp.asarray(pos, jnp.int32))
+            cache = _map_slot(
+                cache, one,
+                lambda full, o, ax: jax.lax.dynamic_update_slice_in_dim(
+                    full, o.astype(full.dtype), slot, axis=ax))
+            return logits[0], cache
+
+        self._slot_decode = jax.jit(slot_decode_step)
 
         def prefill(params, batch):
             return model.prefill(params, batch, s_max=cfg.s_max)
@@ -258,8 +276,9 @@ class ServingEngine:
 
     def step(self) -> Dict[int, int]:
         """One greedy decode step for every active slot.  Returns
-        {rid: token}.  Per-slot positions differ, so slots run their own
-        decode_step (jit'd once; static shapes).
+        {rid: token}.  Per-slot positions differ, so each slot runs its
+        own program (``_decode_slot``; compiled once for every slot and
+        position).
 
         The whole step is one persistence epoch: every slot's token-log
         row and table entry flush once at the closing commit, not once
@@ -327,32 +346,18 @@ class ServingEngine:
         return tlen
 
     def _decode_slot(self, slot: int, token: int, p: int):
-        # extract the slot's cache, run decode at B=1, re-seat it.  A
-        # ready slot is never a re-prefill target, so the extracted rows
-        # cannot change underneath the decode — but the re-seat is a
-        # read-modify-write of the SHARED cache tree, which must not
-        # lose a sibling prefill group's scatter during early-admission
-        # decoding (step() inside an on_slot_ready callback while
-        # recovery is still prefilling other slots)
+        """Decode ``token`` at position ``p`` in ``slot``; returns the
+        slot's logits.  The program reads every slot's rows and returns
+        a new tree, so reading ``self.cache``, the dispatch and the
+        assignment all hold the cache lock: a sibling prefill group's
+        scatter during early-admission decoding (step() inside an
+        on_slot_ready callback while recovery still prefills other
+        slots) must not be lost.  Dispatch is asynchronous, so the lock
+        is held only while the program is enqueued."""
         rid = int(self.slot_rid[slot])
-        with obs.span("serve.slot.extract", rid):
-            one = _map_slot(
-                self.cache, self.cache,
-                lambda full, _, ax: jax.lax.dynamic_slice_in_dim(
-                    full, slot, 1, axis=ax))
-        with obs.span("serve.slot.decode", rid):
-            logits, one2 = self._decode(self.params, one,
-                                        jnp.asarray([token], jnp.int32),
-                                        jnp.asarray(p, jnp.int32))
-            logits = logits[0]
-        # the cache updates ONLY here, inside the lock — returning it for
-        # reassignment at the call site would re-introduce the lost-update
-        # window this lock closes
-        with obs.span("serve.slot.reseat", rid), self._cache_lock:
-            self.cache = _map_slot(
-                self.cache, one2,
-                lambda full, o, ax: jax.lax.dynamic_update_slice_in_dim(
-                    full, o.astype(full.dtype), slot, axis=ax))
+        with obs.span("serve.slot.decode", rid), self._cache_lock:
+            logits, self.cache = self._slot_decode(
+                self.params, self.cache, slot, token, p)
         return logits
 
     # ------------------------------------------------------------------

@@ -16,8 +16,7 @@ from repro.configs import base, registry
 from repro.models.model import build
 from repro.serve.engine import EngineConfig, ServingEngine
 
-SLOT_SPANS = ("serve.slot.extract", "serve.slot.decode",
-              "serve.slot.reseat", "serve.sync")
+SLOT_SPANS = ("serve.slot.decode", "serve.sync")
 
 
 @pytest.fixture(scope="module")
