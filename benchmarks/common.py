@@ -34,17 +34,16 @@ BATCH = 64
 
 
 def arena_fields(a=None, **over) -> Dict:
-    """Substrate triple stamped on EVERY bench row (commit protocol,
-    shard count, persisted arena bytes) so rows from different
-    configurations stay self-describing in the JSON artifacts.  Rows
-    with no arena behind them (raw chain primitives, the ckpt restore)
-    stamp ``commit_mode="none"`` and the working-set bytes instead."""
-    f = {"commit_mode": "none", "n_shards": 1, "arena_bytes": 0,
+    """Substrate fields stamped on EVERY bench row (shard count,
+    persisted arena bytes) so rows from different configurations stay
+    self-describing in the JSON artifacts.  Rows with no arena behind
+    them (raw chain primitives, the ckpt restore) stamp the working-set
+    bytes instead."""
+    f = {"n_shards": 1, "arena_bytes": 0,
          "block_bytes": 0, "cache_blocks": 0, "peak_resident_bytes": 0,
          "integrity": False, "integrity_lines": 0}
     if a is not None:
-        f = {"commit_mode": a.commit_mode,
-             "n_shards": int(getattr(a, "n_shards", 1)),
+        f = {"n_shards": int(getattr(a, "n_shards", 1)),
              "arena_bytes": int(sum(r.nbytes for r in a.regions.values())),
              "block_bytes": 0, "cache_blocks": 0, "peak_resident_bytes": 0,
              # checksum-sidecar accounting (DESIGN.md §13) rides on every

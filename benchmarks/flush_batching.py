@@ -132,9 +132,7 @@ def _dll_delete(n_init: int, n_ops: int, batch: int, seed: int = 0) -> Dict:
 
 
 def _sharded_flush(n_shards: int, n_init: int, n_ops: int, batch: int,
-                   group: int, synth_ns: float, seed: int = 0,
-                   commit_mode: str = "barrier",
-                   synth_fence_ns: float = 0.0) -> Dict:
+                   group: int, synth_ns: float, seed: int = 0) -> Dict:
     """Mixed 1:1 insert/delete B+Tree on an ``n_shards`` arena; returns
     the flush-phase wall (epoch drains + commits only) and the exact
     line accounting.  ``n_shards=1`` is the plain single Arena — the
@@ -142,9 +140,7 @@ def _sharded_flush(n_shards: int, n_init: int, n_ops: int, batch: int,
     rng = np.random.default_rng(seed)
     capacity = n_init + n_ops + 1024
     layout = BPTree.layout(max(64, capacity // 4), capacity, "partly")
-    a = open_arena(None, layout, n_shards=n_shards,
-                   synth_line_ns=synth_ns, commit_mode=commit_mode,
-                   synth_fence_ns=synth_fence_ns)
+    a = open_arena(None, layout, n_shards=n_shards, synth_line_ns=synth_ns)
     t = BPTree(a, max(64, capacity // 4), capacity, "partly")
     keyspace = rng.permutation(capacity * 2).astype(np.int64)
     init_keys = keyspace[:n_init]
@@ -215,47 +211,6 @@ def sharded_sweep(n_init: int, n_ops: int, batch: int = 256,
     return rows
 
 
-def shadow_crossover(n_init: int, n_ops: int, batch: int = 64,
-                     group: int = 4,
-                     synth_fence_ns: float = 1_000_000.0,
-                     repeats: int = 2) -> Dict:
-    """Barrier vs shadow commit, n_shards=4, FENCE-dominated regime:
-    small epoch groups so ordering points (3 per committed epoch in
-    barrier mode — data phase, metadata phase, commit seal — vs the
-    shadow mode's single generation flip) dominate the flush wall.
-    The sharded arena's fence spins exact (no sleep wakeup slack), so
-    the regime holds even at ms-scale ``synth_fence_ns`` — scaled, like
-    the sharded sweep's line stall, until the modeled latency clears
-    this host's per-epoch Python overhead; the fence COUNTS are exact
-    at any scale.
-
-    The compared rate charges BOTH modes the barrier row's line count:
-    shadow writes more lines (remap entries + next-epoch collapse), so
-    crediting each mode its own lines would inflate shadow's
-    numerator — the honest quantity is wall time per committed
-    workload."""
-    best: Dict[str, Dict] = {}
-    for _ in range(repeats):
-        for mode in ("barrier", "shadow"):
-            r = _sharded_flush(4, n_init, n_ops, batch, group,
-                               synth_ns=250.0, commit_mode=mode,
-                               synth_fence_ns=synth_fence_ns)
-            if (mode not in best
-                    or r["flush_wall_s"] < best[mode]["flush_wall_s"]):
-                best[mode] = r
-    bar, sh = best["barrier"], best["shadow"]
-    for r in best.values():
-        r["flush_lines_per_s"] = int(
-            bar["lines"] / max(r["flush_wall_s"], 1e-9))
-    return {"workload": "bptree mixed 1:1, n_shards=4, fence-dominated "
-                        "(rate charges both modes the barrier line "
-                        "count)",
-            "synth_fence_ns": synth_fence_ns,
-            "rows": [bar, sh],
-            "speedup": round(bar["flush_wall_s"]
-                             / max(sh["flush_wall_s"], 1e-9), 2)}
-
-
 def paged_parity(n_init: int, n_ops: int, batch: int = 256,
                  group: int = 16, synth_ns: float = 4000.0,
                  repeats: int = 3) -> Dict:
@@ -267,9 +222,9 @@ def paged_parity(n_init: int, n_ops: int, batch: int = 256,
     with ZERO evictions (cache-fitting) the line/dedup/fence accounting
     must be bit-identical and flush lines/s within 5%.  Stall-dominated
     regime (``synth_ns`` per line) — scaled, like the sharded sweep's
-    stall and the shadow crossover's fence, until the modeled latency
-    clears this host's per-epoch Python overhead; the medium-
-    independent counts stay exact at any scale."""
+    stall, until the modeled latency clears this host's per-epoch
+    Python overhead; the medium-independent counts stay exact at any
+    scale."""
     def one(paged: bool) -> Dict:
         rng = np.random.default_rng(0)
         cap = n_init + 64
@@ -356,11 +311,6 @@ def run(n_init: int = 20000, n_ops: int = 20000,
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true")
-    ap.add_argument("--shadow-crossover", action="store_true",
-                    help="run ONLY the barrier-vs-shadow commit "
-                         "comparison at n_shards=4 in the fence-"
-                         "dominated regime; records in --quick mode, "
-                         "asserts >= 1.3x otherwise — the CI gate")
     ap.add_argument("--paged-parity", action="store_true",
                     help="run ONLY the paged-vs-unpaged flush parity "
                          "gate: with the working set inside the block "
@@ -401,17 +351,6 @@ def main() -> int:
         if not args.quick:
             assert pp["lines_per_s_ratio"] >= 0.95, pp
         return 0
-    if args.shadow_crossover:
-        xr = shadow_crossover(4000, 8192, batch=64, group=4)
-        for r in xr["rows"]:
-            print(f"  {r['commit_mode']:>7}: wall {r['flush_wall_s']}s, "
-                  f"{r['fences']} fences, {r['epochs']} epochs, "
-                  f"{r['flush_lines_per_s']} lines/s")
-        print(f"shadow crossover @ n_shards=4: {xr['speedup']}x "
-              f"flush-phase throughput vs barrier")
-        if not args.quick:
-            assert xr["speedup"] >= 1.3, xr
-        return 0
     n_init, n_ops = (4000, 4000) if args.quick else (20000, 20000)
     rows = run(n_init, n_ops)
     from benchmarks.common import fmt_table
@@ -433,14 +372,6 @@ def main() -> int:
                                  "lines_per_s", "x_vs_1shard", "epochs",
                                  "fences"]))
 
-    crossover = shadow_crossover(4000, 8192, batch=64, group=4)
-    for r in crossover["rows"]:
-        print(f"  {r['commit_mode']:>7}: wall {r['flush_wall_s']}s, "
-              f"{r['fences']} fences, {r['epochs']} epochs, "
-              f"{r['flush_lines_per_s']} lines/s")
-    print(f"shadow crossover @ n_shards=4: {crossover['speedup']}x "
-          f"flush-phase throughput vs barrier")
-
     with open(args.out, "w") as f:
         json.dump({"workload": "bptree mixed 1:1 insert/delete",
                    "n_init": n_init, "n_ops": n_ops, "rows": rows,
@@ -449,8 +380,7 @@ def main() -> int:
                                    "(epoch drain + commit), stall-"
                                    "dominated regime",
                        "synth_line_ns": synth_ns,
-                       "rows": shard_rows},
-                   "shadow_crossover": crossover}, f, indent=1)
+                       "rows": shard_rows}}, f, indent=1)
     print(f"-> {args.out}")
     # epoch batching must never regress per-call accounting, and the
     # grouped B+Tree mixed workload + DLL deletes must beat it outright
@@ -462,11 +392,6 @@ def main() -> int:
     assert x4 >= 1.0, shard_rows
     if not args.quick:
         assert x4 >= 1.3, shard_rows
-        # one ordering point per committed epoch instead of three: the
-        # fence-dominated regime must convert that into >= 1.3x flush-
-        # phase throughput (the dedicated --shadow-crossover step gates
-        # this on CI; quick mode records without asserting)
-        assert crossover["speedup"] >= 1.3, crossover
     return 0
 
 

@@ -114,7 +114,7 @@ class FlushStats:
     calls: int = 0
     fence_ns: int = 0      # synthetic latency accumulated (if enabled)
     fences: int = 0        # ordering points paid (barrier phases + commit
-                           # seals in barrier mode; ONE flip in shadow mode)
+                           # seals)
     # epoch-flush (write-set) counters — DESIGN.md §2
     epochs: int = 0        # batched epoch flushes performed
     marks: int = 0         # mark_rows calls absorbed by the write set
@@ -176,9 +176,8 @@ class _RowAccess:
         for eviction); resident regions need no bookkeeping."""
 
     def _note_persisted(self, rows: np.ndarray) -> None:
-        """Rows just written home by a DIRECT (epoch-less) persist call
-        — the paged override additionally keeps shadow-masked rows
-        dirty, since a refault would overlay the stale mirror."""
+        """Rows just written home by a DIRECT (epoch-less) persist call;
+        a paged region clears their dirty bits like ``_note_flushed``."""
 
     def _note_persisted_range(self, lo: int, hi: int) -> None:
         pass
@@ -265,24 +264,19 @@ class Region(_RowAccess):
         self._note_persisted(rows)
         self.arena._integrity_home(self, rows)
 
-    def mark_rows(self, rows: np.ndarray, fresh: bool = False) -> None:
+    def mark_rows(self, rows: np.ndarray) -> None:
         """Add rows to the arena's write set (flushed once, deduplicated,
         when the enclosing epoch closes).  Outside any epoch this
         degrades to an immediate ``persist_rows`` — per-op call sites
-        behave identically either way.  ``fresh=True`` asserts the rows
-        were never reachable from any committed generation (fresh-range
-        allocations above the committed high-water mark), so a shadow
-        drain may write them home in place instead of through the
-        remap; barrier mode ignores the hint."""
+        behave identically either way."""
         if self.arena._epoch_depth > 0:
-            self.arena.writeset.mark(self, np.asarray(rows, np.int64),
-                                     fresh=fresh)
+            self.arena.writeset.mark(self, np.asarray(rows, np.int64))
         else:
             self.persist_rows(rows)
 
-    def mark_range(self, lo: int, hi: int, fresh: bool = False) -> None:
+    def mark_range(self, lo: int, hi: int) -> None:
         if hi > lo:
-            self.mark_rows(np.arange(lo, hi, dtype=np.int64), fresh=fresh)
+            self.mark_rows(np.arange(lo, hi, dtype=np.int64))
 
     def persist_range(self, lo: int, hi: int) -> None:
         if hi <= lo:
@@ -304,7 +298,6 @@ class Region(_RowAccess):
         Pays the synthetic media read latency when the arena models one
         — the recovery-side mirror of the flush stall."""
         self.vol = np.array(self._pview())
-        self.arena._shadow_overlay(self)
         self.arena.synth_read(self.nbytes)
 
 
@@ -312,11 +305,9 @@ class Arena:
     """File-backed persistent arena with flush accounting."""
 
     def __init__(self, path: Optional[str], synth_line_ns: float = 0.0,
-                 pack_flush_rows: int = 0, commit_mode: str = "barrier",
-                 synth_fence_ns: float = 0.0, paged: Optional[bool] = None,
+                 pack_flush_rows: int = 0, paged: Optional[bool] = None,
                  block_bytes: int = 4096, cache_blocks: int = 1024,
                  integrity: Optional[bool] = None):
-        assert commit_mode in ("barrier", "shadow")
         self.path = path
         self.regions: Dict[str, Region] = {}
         self.stats = FlushStats()
@@ -337,8 +328,6 @@ class Arena:
             from repro.core.paging import BlockCache
             self.cache = BlockCache(self.block_bytes, self.cache_blocks)
         self.synth_line_ns = synth_line_ns
-        self.commit_mode = commit_mode
-        self.synth_fence_ns = synth_fence_ns
         # >0: epoch flushes of at least this many rows gather through the
         # Pallas pack_flush kernel (tile-aligned staging buffer).
         self.pack_flush_rows = pack_flush_rows
@@ -359,24 +348,12 @@ class Arena:
         # order-snapshot providers (DESIGN.md §10): callables returning
         # [(region, rows), ...] drained by the write set ONLY inside a
         # commit (never by mid-epoch flushes), so snapshot bytes always
-        # ride the commit protocol of whichever mode is active
+        # ride the commit protocol
         self._snap_providers: List = []
         self._mm: Optional[np.memmap] = None
         self._cursor = 4096  # header page
         self._meta: Dict[str, dict] = {}
         self.generation = 0
-        # shadow-commit state (DESIGN.md §9) — all volatile; the
-        # persistent side (one meta line, two remap-entry banks, and a
-        # per-region mirror per bank) is laid out by finalize() after
-        # the last region
-        self._region_ids: Dict[str, int] = {}
-        self._shadow_meta_off = 0
-        self._shadow_ent_off = [0, 0]
-        self._shadow_cap = 0
-        self._shadow_masks = ({}, {})   # bank -> {region name: bool mask}
-        self._shadow_counts = [0, 0]
-        self._shadow_collapsed = [True, True]
-        self._shadow_auth_bank = 0
 
     # -- epochs -----------------------------------------------------------
     @contextlib.contextmanager
@@ -414,7 +391,6 @@ class Arena:
                 **_slice_kw)
         self._cursor += _align(r.nbytes, LINE)
         self.regions[name] = r
-        self._region_ids[name] = len(self._region_ids)
         self._meta[name] = {"dtype": np.dtype(dtype).str,
                             "shape": list(shape), "offset": r.offset}
         return r
@@ -430,8 +406,6 @@ class Arena:
         if self.integrity:
             self._integrity_layout()
         self._layout_final = True
-        if self.commit_mode == "shadow":
-            self._shadow_layout()
         total = _align(self._cursor, 4096)
         if self.path is None:
             self._mm = np.zeros(total, np.uint8)  # in-memory (tests)
@@ -494,7 +468,7 @@ class Arena:
                         data: Optional[np.ndarray] = None) -> None:
         """Recompute + persist `rows`' sidecar checksums IN PLACE — the
         companion of every home write of the data rows themselves
-        (barrier drains, fresh shadow rows, direct persists), so data
+        (epoch drains and direct persists), so data
         and checksums always move in the same flush phase and a torn
         crash can never split them.  ``data``, when the caller already
         gathered the rows (the epoch drain always has), skips a second
@@ -519,18 +493,9 @@ class Arena:
                 f"arena {self.path!r} header magic {raw!r} corrupt")
 
     def _pimage(self, region) -> np.ndarray:
-        """The COMMITTED persistent image of a region: home bytes plus
-        the authoritative shadow bank's overlay.  A pure read — scrub
-        and salvage never write persistent state."""
-        img = np.array(region._pview())
-        if self.commit_mode == "shadow":
-            mask = self._shadow_masks[self._shadow_auth_bank].get(
-                region.name)
-            if mask is not None and mask.any():
-                rows = np.nonzero(mask)[0]
-                img[rows] = self._shadow_mirror(
-                    region, self._shadow_auth_bank)[rows]
-        return img
+        """The persistent image of a region.  A pure read — scrub and
+        salvage never write persistent state."""
+        return np.array(region._pview())
 
     def verify_region(self, region) -> np.ndarray:
         """Row indices of `region` whose committed persistent bytes fail
@@ -591,17 +556,12 @@ class Arena:
         """Data-before-metadata ordering: drain the write set, flush file
         contents, then set the valid flag (the paper's initialization
         flag bit).  Inside an epoch this flushes the pending marks first,
-        so a commit never orders the flag ahead of its data.  In shadow
-        mode the whole protocol collapses to ONE ordering point — see
-        ``_commit_shadow``.  Spanned as ``persist.commit``
-        (``repro.obs``)."""
+        so a commit never orders the flag ahead of its data.  Spanned as
+        ``persist.commit`` (``repro.obs``)."""
         with obs.span("persist.commit"):
             self._commit()
 
     def _commit(self) -> None:
-        if self.commit_mode == "shadow":
-            self._commit_shadow()
-            return
         self.writeset.flush()
         if isinstance(self._mm, np.memmap):
             self._mm.flush()
@@ -616,228 +576,9 @@ class Arena:
         self._write_header(valid=False)
 
     def _fence(self) -> None:
-        """One ordering point (sfence + drain of outstanding flushes):
-        counted per mode so the barrier-vs-shadow comparison is visible
-        in the stats artifact, and paid synthetically when
-        ``synth_fence_ns`` models the stall."""
+        """One ordering point (sfence + drain of outstanding flushes),
+        counted in ``FlushStats.fences``."""
         self.stats.fences += 1
-        if self.synth_fence_ns:
-            self._stall(int(self.synth_fence_ns))
-
-    # -- shadow commit protocol (DESIGN.md §9) ------------------------------
-    def _shadow_layout(self) -> None:
-        """Persistent shadow areas, appended after the last region: one
-        meta line holding each remap bank's sealed entry count, two
-        remap-entry banks (one per generation parity: the epoch
-        targeting generation T writes bank T%2, so a torn flip never
-        touches the committed bank), and a per-region mirror per bank
-        whose slot index IS the row index — duplicate rewrites of a row
-        are idempotent by construction, and the remap entry is just
-        (region id, row)."""
-        cur = _align(self._cursor, LINE)
-        self._shadow_meta_off = cur
-        cur += LINE
-        self._shadow_cap = max(1, sum(r.shape[0]
-                                      for r in self.regions.values()))
-        for b in (0, 1):
-            self._shadow_ent_off[b] = cur
-            cur += _align(self._shadow_cap * 16, LINE)
-        for r in self.regions.values():
-            r._shadow_off = {}
-            for b in (0, 1):
-                r._shadow_off[b] = cur
-                cur += _align(r.nbytes, LINE)
-        self._cursor = cur
-
-    def _shadow_target_bank(self) -> int:
-        return (self.generation + 1) % 2
-
-    def _shadow_mirror(self, region: "Region", bank: int) -> np.ndarray:
-        flat = np.frombuffer(self._mm, dtype=np.uint8, count=region.nbytes,
-                             offset=region._shadow_off[bank])
-        return flat.view(region.dtype).reshape(region.shape)
-
-    def _shadow_entries(self, bank: int) -> np.ndarray:
-        flat = np.frombuffer(self._mm, dtype=np.uint8,
-                             count=self._shadow_cap * 16,
-                             offset=self._shadow_ent_off[bank])
-        return flat.view(np.int64).reshape(self._shadow_cap, 2)
-
-    def _shadow_meta_view(self) -> np.ndarray:
-        flat = np.frombuffer(self._mm, dtype=np.uint8, count=LINE,
-                             offset=self._shadow_meta_off)
-        return flat.view(np.int64)
-
-    def _shadow_write(self, region: "Region", rows: np.ndarray) -> None:
-        """Route a rewrite through the remap: the new row versions land
-        in the target bank's mirror (slot = row) and first-touch rows
-        append a (region, row) remap entry.  Committed home rows are
-        never rewritten before the flip, so the drain needs no ordering
-        against the metadata that references them."""
-        b = self._shadow_target_bank()
-        mask = self._shadow_masks[b].get(region.name)
-        if mask is None:
-            mask = self._shadow_masks[b][region.name] = \
-                np.zeros(region.shape[0], bool)
-        new = rows[~mask[rows]]
-        mask[rows] = True
-        self._shadow_mirror(region, b)[rows] = region._gather(rows)
-        self._account_rows(region._shadow_off[b], region.rowbytes, rows,
-                           snap=region.snap, jrnl=region.jrnl,
-                           integ=region.integ)
-        if new.size:
-            cnt = self._shadow_counts[b]
-            ents = self._shadow_entries(b)
-            ents[cnt:cnt + new.size, 0] = self._region_ids[region.name]
-            ents[cnt:cnt + new.size, 1] = new
-            self._account_range(self._shadow_ent_off[b] + cnt * 16,
-                                int(new.size) * 16, snap=region.snap,
-                                jrnl=region.jrnl, integ=region.integ)
-            self._shadow_counts[b] = cnt + int(new.size)
-        # The rows' volatile values are now captured persistently in the
-        # target-bank mirror, which a paged refault overlays — so their
-        # dirty bits may clear (clean blocks become evictable).
-        region._note_flushed(rows)
-        # cascade: the rows' checksums route through the SAME bank, so a
-        # discarded target bank drops data and checksums together
-        sc = region._integ
-        if sc is not None:
-            sc.write_rows(rows, sidecar_checksums(region._gather(rows),
-                                                  sc.shape[1]))
-            self._shadow_write(sc, rows)
-
-    def _shadow_collapse(self, limit: Optional[int] = None) -> bool:
-        """Fold the committed bank's shadow rows into their home slots —
-        the stale-row reclamation, deferred into the next drain instead
-        of blocking the commit that created them.  The copy is
-        value-identical to what recovery would overlay, so a crash at
-        ANY instant during it (the double-failure window) changes
-        nothing the committed generation can observe.  ``limit`` bounds
-        the number of regions folded (crash-injection hook); returns
-        whether the bank fully collapsed."""
-        b = self.generation % 2
-        if self._shadow_collapsed[b]:
-            return True
-        done = True
-        for i, name in enumerate(sorted(self._shadow_masks[b])):
-            if limit is not None and i >= limit:
-                done = False
-                break
-            rows = np.nonzero(self._shadow_masks[b][name])[0]
-            if rows.size == 0:
-                continue
-            region = self.regions[name]
-            region._pview()[rows] = self._shadow_mirror(region, b)[rows]
-            self._account_rows(region.offset, region.rowbytes, rows,
-                               snap=region.snap, jrnl=region.jrnl,
-                               integ=region.integ)
-        if done:
-            self._shadow_collapsed[b] = True
-        return done
-
-    def _shadow_seal(self) -> None:
-        """Persist the target bank's entry count.  Safe before the
-        flip: the bank is dead weight until the generation pointer
-        selects it, and the committed bank's count slot is untouched."""
-        b = self._shadow_target_bank()
-        self._shadow_meta_view()[b] = self._shadow_counts[b]
-        self._account_range(self._shadow_meta_off + b * 8, 8)
-
-    def _shadow_retire(self) -> None:
-        """Post-flip bookkeeping: the previous bank's entries are dead
-        (their rows were folded home before the flip); the newly
-        committed bank awaits its fold at the next drain."""
-        live = self.generation % 2
-        dead = 1 - live
-        self._shadow_masks[dead].clear()
-        self._shadow_counts[dead] = 0
-        self._shadow_collapsed[dead] = True
-        self._shadow_collapsed[live] = self._shadow_counts[live] == 0
-        self._shadow_auth_bank = live
-
-    def _commit_shadow(self) -> None:
-        """Shadow commit: fold the previous epoch's shadow rows home,
-        drain the write set straight through — fresh rows in place,
-        rewrites into the target bank — seal the target bank's count,
-        then pay the ONE ordering point and flip the generation
-        pointer.  The flip atomically reassigns bank authority; a torn
-        flip leaves the committed bank (untouched since its own seal)
-        authoritative, and the orphaned target bank is discarded by
-        never being selected."""
-        self._shadow_collapse()
-        self.writeset.flush()
-        self._shadow_seal()
-        if isinstance(self._mm, np.memmap):
-            self._mm.flush()
-        self._fence()                      # the single ordering point
-        self.generation += 1
-        self._write_header(valid=True)
-        if isinstance(self._mm, np.memmap):
-            self._mm.flush()
-        self.stats.calls += 1
-        self._shadow_retire()
-
-    def _shadow_discard(self) -> None:
-        """Volatile shadow bookkeeping dies with a crash; ``reopen``
-        re-parses it from the committed bank."""
-        for m in self._shadow_masks:
-            m.clear()
-        self._shadow_counts = [0, 0]
-        self._shadow_collapsed = [True, True]
-
-    def _shadow_parse(self, authority_gen: Optional[int] = None) -> None:
-        """Post-crash: rebuild the volatile remap masks from the bank
-        the COMMITTED generation pointer selects — for a shard that is
-        the manifest generation, which may trail the shard's own header
-        if the flip tore between shards.  Entries in the other bank (a
-        torn flip's orphans) are never selected and are overwritten
-        when that bank is next targeted."""
-        if self.commit_mode != "shadow":
-            return
-        gen = self.header_generation() if authority_gen is None \
-            else authority_gen
-        b = gen % 2
-        cnt = int(self._shadow_meta_view()[b])
-        ents = np.array(self._shadow_entries(b)[:cnt])
-        masks: Dict[str, np.ndarray] = {}
-        names = list(self.regions)
-        for rid in (np.unique(ents[:, 0]) if cnt else ()):
-            name = names[int(rid)]
-            mask = np.zeros(self.regions[name].shape[0], bool)
-            mask[ents[ents[:, 0] == rid, 1]] = True
-            masks[name] = mask
-        self._shadow_masks = (masks, {}) if b == 0 else ({}, masks)
-        self._shadow_counts = [cnt, 0] if b == 0 else [0, cnt]
-        self._shadow_collapsed = [True, True]
-        self._shadow_collapsed[b] = cnt == 0
-        self._shadow_auth_bank = b
-        # re-anchor bank targeting to the COMMITTED generation: a shard
-        # whose header flipped ahead of a torn manifest write must aim
-        # its next drain at the bank the manifest's parity dooms, not
-        # keep writing into the bank recovery just selected
-        self.generation = gen
-
-    def _shadow_overlay(self, region: "Region",
-                        vol: Optional[np.ndarray] = None,
-                        gidx: Optional[np.ndarray] = None) -> None:
-        """Apply the authoritative bank's shadow rows over a freshly
-        loaded volatile copy — recovery-side only, and VOLATILE-only:
-        recovery persists nothing (the fold happens lazily at the next
-        drain, preserving reconstructor purity)."""
-        if self.commit_mode != "shadow":
-            return
-        mask = self._shadow_masks[self._shadow_auth_bank].get(region.name)
-        if mask is None:
-            return
-        rows = np.nonzero(mask)[0]
-        if rows.size == 0:
-            return
-        m = self._shadow_mirror(region, self._shadow_auth_bank)
-        if vol is None:
-            region.vol[rows] = m[rows]
-        else:
-            vol[gidx[rows]] = m[rows]
-        self.synth_read(int(rows.size) * region.rowbytes)
 
     # -- crash simulation ---------------------------------------------------
     def crash(self) -> None:
@@ -846,17 +587,13 @@ class Arena:
         un-flushed rows; it must never flush zeroed volatile copies over
         committed data when a wrapping epoch unwinds."""
         self.writeset.discard()
-        self._shadow_discard()
         for r in self.regions.values():
             r._crash_reset()
 
     def reopen(self) -> None:
         """Reload every region's volatile copy from persistent memory,
         and re-anchor the in-memory generation counter to the committed
-        one (a fresh process starts at 0 otherwise).  Shadow mode first
-        re-parses the committed bank's remap so each load overlays the
-        flipped-in row versions."""
-        self._shadow_parse()
+        one (a fresh process starts at 0 otherwise)."""
         for r in self.regions.values():
             r.load()
         self.generation = max(self.generation, self.header_generation())
@@ -1283,8 +1020,6 @@ class _ShardSlice(Region):
 
     def load(self) -> None:
         self._parent.vol[self._gidx] = self._pview()
-        self.arena._shadow_overlay(self, vol=self._parent.vol,
-                                   gidx=self._gidx)
 
 
 class ShardedRegion(_RowAccess):
@@ -1368,20 +1103,20 @@ class ShardedRegion(_RowAccess):
             yield self.slices[s], self.local_of[sel]
 
     # -- Region API --------------------------------------------------------
-    def mark_rows(self, rows: np.ndarray, fresh: bool = False) -> None:
+    def mark_rows(self, rows: np.ndarray) -> None:
         rows = np.asarray(rows, np.int64)
         if rows.size == 0:
             return
         if self.arena._epoch_depth > 0:
             # buffered globally; the row->shard split happens once per
             # epoch at flush (ShardedWriteSet.mark documents why)
-            self.arena.writeset.mark(self, rows, fresh=fresh)
+            self.arena.writeset.mark(self, rows)
         else:
             self.persist_rows(rows)
 
-    def mark_range(self, lo: int, hi: int, fresh: bool = False) -> None:
+    def mark_range(self, lo: int, hi: int) -> None:
         if hi > lo:
-            self.mark_rows(np.arange(lo, hi, dtype=np.int64), fresh=fresh)
+            self.mark_rows(np.arange(lo, hi, dtype=np.int64))
 
     def persist_rows(self, rows: np.ndarray) -> None:
         rows = np.asarray(rows, np.int64)
@@ -1431,7 +1166,6 @@ class ShardedRegion(_RowAccess):
                 self.vol[nb * B:] = pv[nfull * B:]
         else:
             self.vol[sl._gidx] = pv
-        sl.arena._shadow_overlay(sl, vol=self.vol, gidx=sl._gidx)
         # per-shard media read stall — sleeps in the shard pool, so N
         # shards' reload stalls overlap instead of summing
         sl.arena.synth_read(sl.nbytes)
@@ -1453,12 +1187,10 @@ class ShardedArena:
 
     def __init__(self, path: Optional[str], n_shards: int = 2,
                  synth_line_ns: float = 0.0, pack_flush_rows: int = 0,
-                 commit_mode: str = "barrier", synth_fence_ns: float = 0.0,
                  paged: Optional[bool] = None, block_bytes: int = 4096,
                  cache_blocks: int = 1024,
                  integrity: Optional[bool] = None):
         assert n_shards >= 1
-        assert commit_mode in ("barrier", "shadow")
         self.path = path
         self.n_shards = int(n_shards)
         # sidecars are declared at the SHARDED level (same router as
@@ -1470,8 +1202,7 @@ class ShardedArena:
         # sharded level, so shards are always opened unpaged
         self.shards = [Arena(f"{path}.s{k}" if path else None,
                              synth_line_ns, pack_flush_rows,
-                             commit_mode=commit_mode, paged=False,
-                             integrity=False)
+                             paged=False, integrity=False)
                        for k in range(self.n_shards)]
         self.paged = paged_enabled(paged)
         self.block_bytes = int(block_bytes)
@@ -1484,10 +1215,6 @@ class ShardedArena:
             sh.synth_sleep = True
         self.synth_line_ns = synth_line_ns
         self.pack_flush_rows = pack_flush_rows
-        self.commit_mode = commit_mode
-        # the fence is a GLOBAL ordering point, so its synthetic stall
-        # lives at the sharded level, never per shard
-        self.synth_fence_ns = synth_fence_ns
         self.regions: Dict[str, ShardedRegion] = {}
         self.writeset = ShardedWriteSet(self)
         self.generation = 0
@@ -1617,20 +1344,11 @@ class ShardedArena:
             sh.verify_header()
 
     def _pimage(self, region: "ShardedRegion") -> np.ndarray:
-        """Committed persistent image assembled across shards (home
-        bytes + each shard's authoritative bank overlay) — pure read."""
+        """Persistent image assembled across shards — pure read."""
         img = np.zeros(region.shape, region.dtype)
         for sl in region.slices:
-            if sl is None:
-                continue
-            img[sl._gidx] = sl._pview()
-            sh = sl.arena
-            if sh.commit_mode == "shadow":
-                mask = sh._shadow_masks[sh._shadow_auth_bank].get(sl.name)
-                if mask is not None and mask.any():
-                    rows = np.nonzero(mask)[0]
-                    img[sl._gidx[rows]] = sh._shadow_mirror(
-                        sl, sh._shadow_auth_bank)[rows]
+            if sl is not None:
+                img[sl._gidx] = sl._pview()
         return img
 
     def verify_region(self, region) -> np.ndarray:
@@ -1691,52 +1409,22 @@ class ShardedArena:
 
     def _fence(self) -> None:
         """The global ordering point — one per barrier phase plus one
-        per commit seal in barrier mode, exactly ONE per shadow commit."""
+        per commit seal."""
         self._local_stats.fences += 1
-        if self.synth_fence_ns:
-            ns = int(self.synth_fence_ns)
-            self._local_stats.fence_ns += ns
-            t0 = time.perf_counter_ns()
-            while time.perf_counter_ns() - t0 < ns:
-                pass
 
     def commit(self, _crash_after_shard: Optional[int] = None) -> None:
-        """Drain write sets (global data-before-metadata in barrier
-        mode), commit each shard, manifest LAST.  ``_crash_after_shard=k``
-        is the crash-injection hook for the inter-shard commit window:
-        shards 0..k commit, then power fails before the manifest — the
-        fuzzer's sweep point (tests/test_sharded_arena.py).
-
-        Shadow mode: fold every shard's previous bank home and drain the
-        write set in one pooled phase (no cross-shard barrier), seal
-        each shard's target bank, pay the SINGLE ordering point, then
-        flip every shard's header and write the manifest last — the
-        existing cross-shard atomicity protocol carries over unchanged.
-        ``_crash_after_shard=-1`` crashes after the seals but before any
-        flip (the torn-flip window's leading edge).  Spanned as
+        """Drain write sets (global data-before-metadata), commit each
+        shard, manifest LAST.  ``_crash_after_shard=k`` is the
+        crash-injection hook for the inter-shard commit window: shards
+        0..k commit, then power fails before the manifest — the fuzzer's
+        sweep point (tests/test_sharded_arena.py).  Spanned as
         ``persist.commit`` (``repro.obs``)."""
         with obs.span("persist.commit"):
             self._commit(_crash_after_shard)
 
     def _commit(self, _crash_after_shard: Optional[int]) -> None:
-        if self.commit_mode == "shadow":
-            if self.n_shards > 1:
-                list(self.pool().map(lambda sh: sh._shadow_collapse(),
-                                     self.shards))
-            else:
-                self.shards[0]._shadow_collapse()
-            self.writeset.flush()
-            for sh in self.shards:
-                sh._shadow_seal()
-                if isinstance(sh._mm, np.memmap):
-                    sh._mm.flush()
-            if _crash_after_shard is not None and _crash_after_shard < 0:
-                self.crash()
-                return
-            self._fence()                  # the single ordering point
-        else:
-            self.writeset.flush()
-            self._fence()
+        self.writeset.flush()
+        self._fence()
         tgt = self.generation + 1
         for k, sh in enumerate(self.shards):
             if isinstance(sh._mm, np.memmap):
@@ -1751,9 +1439,6 @@ class ShardedArena:
         self.generation = tgt
         self._write_manifest(valid=True)
         self._local_stats.calls += 1
-        if self.commit_mode == "shadow":
-            for sh in self.shards:
-                sh._shadow_retire()
 
     def invalidate(self) -> None:
         self._write_manifest(valid=False)
@@ -1766,8 +1451,6 @@ class ShardedArena:
         the post-crash reload writes warm pages — allocator churn and
         page faults stay out of the recovery-critical path."""
         self.writeset.discard()
-        for sh in self.shards:
-            sh._shadow_discard()
         for r in self.regions.values():
             r._crash_reset()
 
@@ -1779,13 +1462,6 @@ class ShardedArena:
         then re-anchor the generation to the manifest's.  ``exclude``
         names regions the caller will load itself (RecoveryManager's
         per-region load stages)."""
-        # shadow bank authority is the MANIFEST generation: a shard whose
-        # header flipped ahead of a torn manifest write must still
-        # overlay the manifest generation's bank (intact by parity, and
-        # value-identical to its own already-folded home rows)
-        man_gen = self.header_generation()
-        for sh in self.shards:
-            sh._shadow_parse(authority_gen=man_gen)
         regions = [r for n, r in self.regions.items() if n not in exclude]
         # paged regions reload lazily: one cheap block-pool reset, and
         # the post-crash working set faults in on demand

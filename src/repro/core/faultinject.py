@@ -2,15 +2,9 @@
 
 The integrity layer's whole claim — every single-line corruption in
 committed territory is *detected or harmless* — is only as strong as
-the injector behind the sweep.  These helpers corrupt the COMMITTED
-LOGICAL IMAGE of a row, not merely some bytes at its home offset:
-under ``commit_mode="shadow"`` a committed row may live in the
-authoritative remap bank's mirror rather than its home slot, so the
-injector parses the PERSISTENT bank state (generation parity, sealed
-entry counts, remap entries) exactly the way post-crash recovery does,
-and lands the fault where recovery will actually read.  Injecting at
-the home slot of a bank-remapped row would corrupt dead bytes and
-prove nothing.
+the injector behind the sweep.  These helpers corrupt the committed
+image of a row at its home slot in the owning shard's mapping, where
+post-crash recovery reads it.
 
 Faults by taxonomy (core.arena error types):
 
@@ -46,25 +40,14 @@ __all__ = [
 def committed_row_offset(arena, region, row: int) -> Tuple[Arena, int, int]:
     """(owning plain arena, byte offset of the row's committed image in
     that arena's mapping, rowbytes).  Resolves sharded regions to the
-    owning shard and shadow-remapped rows to the authoritative bank's
-    mirror slot by parsing persistent state only — valid before or
-    after a crash, in either commit mode."""
+    owning shard — valid before or after a crash."""
     if isinstance(region, str):
         region = arena.regions[region]
     if isinstance(arena, ShardedArena):
         s = int(region.shard_of[row])
         return committed_row_offset(arena.shards[s], region.slices[s],
                                     int(region.local_of[row]))
-    base = region.offset
-    if arena.commit_mode == "shadow":
-        bank = arena.header_generation() % 2
-        cnt = int(arena._shadow_meta_view()[bank])
-        if cnt:
-            ents = np.array(arena._shadow_entries(bank)[:cnt])
-            rid = arena._region_ids[region.name]
-            if bool(((ents[:, 0] == rid) & (ents[:, 1] == row)).any()):
-                base = region._shadow_off[bank]
-    return arena, base + row * region.rowbytes, region.rowbytes
+    return arena, region.offset + row * region.rowbytes, region.rowbytes
 
 
 def flip_bits(arena, region, row: int, byte: int = 0,

@@ -8,24 +8,23 @@ blocks (default 4 KiB — the same granularity as the sharded
 block-copy load fast path) faulted in on demand through a per-arena
 LRU ``BlockCache``:
 
-* a FAULT assembles the block from its authoritative persistent bytes:
-  the home slot overlaid with BOTH shadow banks (committed authority
-  first, then the in-flight target bank — newer wins), so a refaulted
-  block is always bit-identical to the volatile view it replaces;
+* a FAULT assembles the block from its home slots in persistent memory,
+  so a refaulted clean block is bit-identical to the volatile view it
+  replaces;
 * a CLEAN block is therefore pure cache: eviction is a free drop;
 * a DIRTY block (unflushed ``write_*`` rows) is PINNED — it holds the
   only copy of those rows, and mid-epoch home write-back would tear
   the committed generation's data-before-metadata invariant.  Dirty
   blocks write back exclusively through the existing write-set drain
-  (``_note_flushed``) or the shadow remap, i.e. the epoch flush IS the
-  write-back path, so commit semantics are unchanged in both modes;
+  (``_note_flushed``), i.e. the epoch flush IS the write-back path, so
+  commit semantics are unchanged;
 * recovery's ``load:`` stages become lazy block-pool resets; the
   reconstructors fault exactly the blocks they touch, so recovery cost
   tracks the working set, not the arena size (the OID/node-cache
   indirection the ROADMAP item names).
 
 Consumers that still grab the full ``.vol`` array trigger a one-shot
-SPILL: the region materializes (home + overlays + dirty resident rows)
+SPILL: the region materializes (home + dirty resident rows)
 and leaves paged mode until the next ``load()``/crash.  Counted in
 ``BlockCache.spills`` — correctness fallback, not a fast path.
 """
@@ -128,8 +127,8 @@ class BlockCache:
 class _BlockPool:
     """Demand-faulted block pool shared by PagedRegion and
     PagedShardedRegion.  Subclasses provide ``_assemble(lo, hi)`` (the
-    authoritative fault read) and ``_masked_rows(rows)`` (which rows a
-    shadow bank currently remaps)."""
+    fault read) and ``_integ_ref(lo, hi)`` (the sidecar checksums the
+    fault verifies against)."""
 
     is_paged = True
 
@@ -215,12 +214,10 @@ class _BlockPool:
         return blk
 
     def _verify_block(self, blk: np.ndarray, lo: int, hi: int) -> None:
-        """Check an assembled block against its sidecar checksums.  The
-        reference is assembled EXACTLY like the data (home + authority
-        bank + in-flight target bank, newer wins): data rows and their
-        sidecar lines always move in the same flush phase and bank, so
-        every flushed row has a matching reference and never-flushed
-        rows carry the 0 sentinel and are skipped."""
+        """Check an assembled block against its sidecar checksums.
+        Data rows and their sidecar lines always move in the same flush
+        phase, so every flushed row has a matching reference and
+        never-flushed rows carry the 0 sentinel and are skipped."""
         sc = self._integ
         if sc is None:
             return
@@ -339,8 +336,7 @@ class _BlockPool:
 
     # -- write-back bookkeeping --------------------------------------------
     def _note_flushed(self, rows: np.ndarray) -> None:
-        """Rows persisted by the write-set drain (home write in barrier
-        mode, target-bank mirror in shadow mode — both refault-visible):
+        """Rows persisted home by the write-set drain (refault-visible):
         clear their dirty bits so their blocks become evictable."""
         if self._spill is not None:
             return
@@ -350,25 +346,10 @@ class _BlockPool:
         with self._cache.lock:
             self._dirty_rows[rows] = False
 
-    def _set_dirty(self, rows: np.ndarray) -> None:
-        with self._cache.lock:
-            self._dirty_rows[rows] = True
-
     def _note_persisted(self, rows: np.ndarray) -> None:
         """Direct (epoch-less) persist wrote these rows home — as
-        durable as a flush EXCEPT where a shadow bank still remaps the
-        row: a refault would overlay the stale mirror over the newer
-        home bytes, so those rows stay dirty (their blocks pinned)."""
-        if self._spill is not None:
-            return
-        rows = np.asarray(rows, np.int64)
-        if rows.size == 0:
-            return
-        with self._cache.lock:
-            masked = self._masked_rows(rows)
-            if masked.any():
-                self._set_dirty(rows[masked])
-            self._note_flushed(rows[~masked])
+        durable as a flush."""
+        self._note_flushed(rows)
 
     def _note_persisted_range(self, lo: int, hi: int) -> None:
         self._note_persisted(np.arange(lo, hi, dtype=np.int64))
@@ -403,49 +384,17 @@ class _BlockPool:
 
 class PagedRegion(_BlockPool, Region):
     """Single-arena paged region: blocks assemble from this arena's
-    home slots + its two shadow banks."""
-
-    def _masked_rows(self, rows: np.ndarray) -> np.ndarray:
-        out = np.zeros(rows.size, bool)
-        a = self.arena
-        if a.commit_mode != "shadow":
-            return out
-        for bank in (0, 1):
-            mask = a._shadow_masks[bank].get(self.name)
-            if mask is not None:
-                out |= mask[rows]
-        return out
+    home slots."""
 
     def _assemble(self, lo: int, hi: int) -> np.ndarray:
         blk = np.array(self._pview()[lo:hi])
-        a = self.arena
-        if a.commit_mode == "shadow":
-            auth = a._shadow_auth_bank
-            for bank in (auth, 1 - auth):   # target bank last: newer wins
-                mask = a._shadow_masks[bank].get(self.name)
-                if mask is not None:
-                    hit = np.nonzero(mask[lo:hi])[0]
-                    if hit.size:
-                        blk[hit] = a._shadow_mirror(self, bank)[lo + hit]
-        a.synth_read(blk.nbytes)
+        self.arena.synth_read(blk.nbytes)
         return blk
 
     def _integ_ref(self, lo: int, hi: int) -> np.ndarray:
-        """Sidecar checksums for rows [lo, hi), assembled with the same
-        overlay order as the data block itself (sidecars are never
+        """Sidecar checksums for rows [lo, hi) (sidecars are never
         paged, so this is a plain persistent read)."""
-        sc = self._integ
-        ref = np.array(sc._pview()[lo:hi])
-        a = self.arena
-        if a.commit_mode == "shadow":
-            auth = a._shadow_auth_bank
-            for bank in (auth, 1 - auth):
-                mask = a._shadow_masks[bank].get(sc.name)
-                if mask is not None:
-                    hit = np.nonzero(mask[lo:hi])[0]
-                    if hit.size:
-                        ref[hit] = a._shadow_mirror(sc, bank)[lo + hit]
-        return ref
+        return np.array(self._integ._pview()[lo:hi])
 
     def load(self) -> None:
         """Lazy reload: drop every block.  The post-crash working set
@@ -459,23 +408,7 @@ class PagedRegion(_BlockPool, Region):
 class PagedShardedRegion(_BlockPool, ShardedRegion):
     """Sharded paged region: ONE block pool at the sharded level (the
     cache replaces the one full-shape volatile image); each fault
-    gathers its rows from the owning shards' slices and applies each
-    shard's own bank overlays with LOCAL row masks."""
-
-    def _masked_rows(self, rows: np.ndarray) -> np.ndarray:
-        out = np.zeros(rows.size, bool)
-        sh = self.shard_of[rows]
-        for s in np.unique(sh):
-            shard = self.arena.shards[s]
-            if shard.commit_mode != "shadow":
-                continue
-            pos = np.nonzero(sh == s)[0]
-            lr = self.local_of[rows[pos]]
-            for bank in (0, 1):
-                mask = shard._shadow_masks[bank].get(self.name)
-                if mask is not None:
-                    out[pos] |= mask[lr]
-        return out
+    gathers its rows from the owning shards' slices."""
 
     def _assemble(self, lo: int, hi: int) -> np.ndarray:
         blk = np.empty((hi - lo,) + self.shape[1:], self.dtype)
@@ -485,18 +418,8 @@ class PagedShardedRegion(_BlockPool, ShardedRegion):
             pos = np.nonzero(sh == s)[0]
             sl = self.slices[s]
             lr = self.local_of[grows[pos]]
-            sub = sl._pview()[lr]
-            shard = self.arena.shards[s]
-            if shard.commit_mode == "shadow":
-                auth = shard._shadow_auth_bank
-                for bank in (auth, 1 - auth):
-                    mask = shard._shadow_masks[bank].get(self.name)
-                    if mask is not None:
-                        hit = np.nonzero(mask[lr])[0]
-                        if hit.size:
-                            sub[hit] = shard._shadow_mirror(sl, bank)[lr[hit]]
-            blk[pos] = sub
-            shard.synth_read(int(pos.size) * self.rowbytes)
+            blk[pos] = sl._pview()[lr]
+            self.arena.shards[s].synth_read(int(pos.size) * self.rowbytes)
         return blk
 
     def _integ_ref(self, lo: int, hi: int) -> np.ndarray:
@@ -508,17 +431,7 @@ class PagedShardedRegion(_BlockPool, ShardedRegion):
             pos = np.nonzero(sh == s)[0]
             sl = sc.slices[s]
             lr = sc.local_of[grows[pos]]
-            sub = np.array(sl._pview()[lr])
-            shard = self.arena.shards[s]
-            if shard.commit_mode == "shadow":
-                auth = shard._shadow_auth_bank
-                for bank in (auth, 1 - auth):
-                    mask = shard._shadow_masks[bank].get(sc.name)
-                    if mask is not None:
-                        hit = np.nonzero(mask[lr])[0]
-                        if hit.size:
-                            sub[hit] = shard._shadow_mirror(sl, bank)[lr[hit]]
-            ref[pos] = sub
+            ref[pos] = sl._pview()[lr]
         return ref
 
     # slice gathers / notes route here with GLOBAL row ids

@@ -828,13 +828,7 @@ class RecoveryManager:
             st = report.add("reopen", reopen_secs,
                             arenas=len(self.arenas), valid=valids,
                             shards=[getattr(a, "n_shards", 1)
-                                    for a in self.arenas],
-                            # which commit protocol the recovered bytes
-                            # came through: "shadow" means reopen also
-                            # selected the committed remap bank and
-                            # discarded orphans from any torn flip (§9)
-                            modes=[getattr(a, "commit_mode", "barrier")
-                                   for a in self.arenas])
+                                    for a in self.arenas])
             st.t_start = t0 - t_all
             st.t_end = st.t_start + reopen_secs
             report.valid = all(valids)

@@ -49,14 +49,12 @@ class WriteSet:
 
     def __init__(self, arena):
         self.arena = arena
-        # region name -> list of (unique rows, per-call line cost, fresh)
-        self._pending: Dict[str, List[Tuple[np.ndarray, int, bool]]] = {}
+        # region name -> list of (unique rows, per-call line cost)
+        self._pending: Dict[str, List[Tuple[np.ndarray, int]]] = {}
 
     # ------------------------------------------------------------- mark
-    def mark(self, region, rows: np.ndarray, fresh: bool = False) -> None:
-        """Record dirty rows of `region`; flushed at epoch close.
-        ``fresh`` rows were never committed-reachable, so a shadow-mode
-        drain writes them home in place (barrier mode ignores it)."""
+    def mark(self, region, rows: np.ndarray) -> None:
+        """Record dirty rows of `region`; flushed at epoch close."""
         rows = np.unique(np.asarray(rows, np.int64))
         if rows.size == 0:
             return
@@ -64,13 +62,11 @@ class WriteSet:
             # snapshot and journal regions stay out of the mark/saved/
             # dedup ledger — their lines land in FlushStats.snapshot_lines
             # / journal_lines at drain
-            self._pending.setdefault(region.name, []).append((rows, 0,
-                                                              fresh))
+            self._pending.setdefault(region.name, []).append((rows, 0))
             return
         would = self.arena._rows_line_count(region.offset, region.rowbytes,
                                             rows)
-        self._pending.setdefault(region.name, []).append((rows, would,
-                                                          fresh))
+        self._pending.setdefault(region.name, []).append((rows, would))
         self.arena.stats.marks += 1
 
     def __bool__(self) -> bool:
@@ -86,18 +82,9 @@ class WriteSet:
         once, copy volatile -> persistent.  Data regions first, then
         metadata regions (headers); ``include_meta=False`` flushes only
         the data half and DROPS the metadata marks — the crash-injection
-        point used by recovery tests.  Shadow mode drains everything in
-        ONE unordered phase (fresh rows home, rewrites into the target
-        bank); ``include_meta=False`` then simply means "crash before
-        the flip" — nothing drained is reachable until commit."""
+        point used by recovery tests."""
         self._drain_snapshots()
         if not self._pending:
-            return
-        if self.arena.commit_mode == "shadow":
-            flushed = self._flush_shadow()
-            self._pending.clear()
-            if flushed:
-                self.arena.stats.epochs += 1
             return
         flushed = self.flush_phase(meta=False)
         if include_meta:
@@ -146,9 +133,9 @@ class WriteSet:
         for name in names:
             region = arena.regions[name]
             marks = self._pending.pop(name)
-            rows = np.unique(np.concatenate([r for r, _, _ in marks]))
-            would_lines = sum(w for _, w, _ in marks)
-            marked_rows = sum(r.size for r, _, _ in marks)
+            rows = np.unique(np.concatenate([r for r, _ in marks]))
+            would_lines = sum(w for _, w in marks)
+            marked_rows = sum(r.size for r, _ in marks)
             g = self._copy_rows(region, rows)
             # the drain IS where checksums ride the write set: data rows
             # and their sidecar lines move in the same phase, same fence
@@ -164,52 +151,6 @@ class WriteSet:
             arena.stats.saved_lines += max(0, would_lines - actual)
             arena.stats.dedup_rows += marked_rows - rows.size
             flushed_any = True
-        return flushed_any
-
-    def _flush_shadow(self) -> bool:
-        """Single-phase shadow drain: every region together, no
-        data-before-metadata ordering — fresh rows go home in place
-        (unreachable until the flip), every other row routes through the
-        arena's remap (arena._shadow_write).  The committed bank's
-        leftovers fold home first (reclamation deferred from the prior
-        commit into this drain)."""
-        arena = self.arena
-        names = sorted(self._pending,
-                       key=lambda n: arena.regions[n].offset)
-        flushed_any = False
-        with arena.stall_scope():
-            arena._shadow_collapse()
-            for name in names:
-                region = arena.regions[name]
-                marks = self._pending.pop(name)
-                rew = [r for r, _, f in marks if not f]
-                frs = [r for r, _, f in marks if f]
-                rew = np.unique(np.concatenate(rew)) if rew \
-                    else np.empty(0, np.int64)
-                fr = np.unique(np.concatenate(frs)) if frs \
-                    else np.empty(0, np.int64)
-                # a row marked both ways is conservatively a rewrite
-                fr = np.setdiff1d(fr, rew, assume_unique=True)
-                would_lines = sum(w for _, w, _ in marks)
-                marked_rows = sum(r.size for r, _, _ in marks)
-                before = arena.stats.lines
-                if fr.size:
-                    g = self._copy_rows(region, fr)
-                    arena._account_rows(region.offset, region.rowbytes, fr,
-                                        snap=region.snap, jrnl=region.jrnl)
-                    # fresh rows flush home, so their checksums do too;
-                    # rewrites cascade inside _shadow_write (same bank)
-                    arena._integrity_home(region, fr, data=g)
-                if rew.size:
-                    arena._shadow_write(region, rew)
-                if region.snap or region.jrnl:
-                    flushed_any = True
-                    continue
-                actual = arena.stats.lines - before
-                arena.stats.saved_lines += max(0, would_lines - actual)
-                arena.stats.dedup_rows += \
-                    marked_rows - int(fr.size) - int(rew.size)
-                flushed_any = True
         return flushed_any
 
     def _copy_rows(self, region, rows: np.ndarray) -> np.ndarray:
@@ -244,11 +185,10 @@ class ShardedWriteSet:
 
     def __init__(self, arena):
         self.arena = arena
-        # region name -> [rewrite row arrays, would_lines, marked,
-        #                 fresh row arrays]
+        # region name -> [row arrays, would_lines, marked]
         self._pending: Dict[str, list] = {}
 
-    def mark(self, region, rows: np.ndarray, fresh: bool = False) -> None:
+    def mark(self, region, rows: np.ndarray) -> None:
         rows = np.unique(np.asarray(rows, np.int64))
         if rows.size == 0:
             return
@@ -262,15 +202,15 @@ class ShardedWriteSet:
         if getattr(region, "snap", False) or getattr(region, "jrnl", False):
             ent = self._pending.get(region.name)
             if ent is None:
-                ent = self._pending[region.name] = [[], 0, 0, []]
-            (ent[3] if fresh else ent[0]).append(rows)
+                ent = self._pending[region.name] = [[], 0, 0]
+            ent[0].append(rows)
             return
         from repro.core.arena import Arena
         would = Arena._rows_line_count(0, region.rowbytes, rows)
         ent = self._pending.get(region.name)
         if ent is None:
-            ent = self._pending[region.name] = [[], 0, 0, []]
-        (ent[3] if fresh else ent[0]).append(rows)
+            ent = self._pending[region.name] = [[], 0, 0]
+        ent[0].append(rows)
         ent[1] += would
         ent[2] += rows.size
         self.arena._local_stats.marks += 1
@@ -294,12 +234,6 @@ class ShardedWriteSet:
         if not self._pending:
             return
         arena = self.arena
-        if arena.commit_mode == "shadow":
-            flushed = self._flush_shadow()
-            self._pending.clear()
-            if flushed:
-                arena._local_stats.epochs += 1
-            return
         flushed = self._flush_phase(meta=False)
         if include_meta:
             flushed = self._flush_phase(meta=True) or flushed
@@ -324,8 +258,7 @@ class ShardedWriteSet:
         region_rows = []
         for name in names:
             region = arena.regions[name]
-            arrs, would, marked, fresh_arrs = self._pending.pop(name)
-            arrs = arrs + fresh_arrs    # barrier mode: the hint is moot
+            arrs, would, marked = self._pending.pop(name)
             rows = np.unique(np.concatenate(arrs)) if len(arrs) > 1 \
                 else arrs[0]
             if not (region.snap or region.jrnl):
@@ -362,68 +295,6 @@ class ShardedWriteSet:
         arena._local_stats.dedup_rows += sum(
             m - r.size for _, r, _, m in region_rows)
         arena._fence()          # the global cross-shard ordering point
-        return True
-
-    def _flush_shadow(self) -> bool:
-        """Pooled SINGLE-phase shadow drain: no cross-shard barrier and
-        no data/metadata split — every shard folds its committed bank's
-        leftovers home, writes fresh rows in place, and routes rewrites
-        through its own remap bank, all concurrently.  Nothing drained
-        here is reachable until the commit's generation flip, which is
-        the one ordering point the whole epoch pays."""
-        arena = self.arena
-        names = sorted(self._pending)
-        if not names:
-            return False
-        work: Dict[int, list] = {}  # shard -> [(slice, local, fresh)]
-        region_rows = []
-        for name in names:
-            region = arena.regions[name]
-            arrs, would, marked, fresh_arrs = self._pending.pop(name)
-            rew = np.unique(np.concatenate(arrs)) if arrs \
-                else np.empty(0, np.int64)
-            fr = np.unique(np.concatenate(fresh_arrs)) if fresh_arrs \
-                else np.empty(0, np.int64)
-            # a row marked both ways is conservatively a rewrite
-            fr = np.setdiff1d(fr, rew, assume_unique=True)
-            if not (region.snap or region.jrnl):
-                # snap/jrnl lines stay off the ledger
-                region_rows.append((would, marked,
-                                    int(fr.size + rew.size)))
-            for sl, local in region._split(rew):
-                work.setdefault(sl.arena_index, []).append(
-                    (sl, np.sort(local), False))
-            for sl, local in region._split(fr):
-                work.setdefault(sl.arena_index, []).append(
-                    (sl, np.sort(local), True))
-
-        actual = {}                     # shard -> lines flushed there
-
-        def flush_shard(s: int) -> None:
-            shard = arena.shards[s]
-            before = shard.stats.lines
-            with shard.stall_scope():
-                shard._shadow_collapse()
-                for sl, local, fresh in work.get(s, ()):
-                    if fresh:
-                        g = self._copy_rows(sl, local)
-                        shard._account_rows(sl.offset, sl.rowbytes, local,
-                                            snap=sl.snap, jrnl=sl.jrnl)
-                        shard._integrity_home(sl, local, data=g)
-                    else:
-                        shard._shadow_write(sl, local)
-            actual[s] = shard.stats.lines - before
-
-        shards = sorted(work)
-        if len(shards) > 1:
-            list(arena.pool().map(flush_shard, shards))
-        elif shards:
-            flush_shard(shards[0])
-        total_actual = sum(actual.values())
-        would_total = sum(w for w, _, _ in region_rows)
-        arena._local_stats.saved_lines += max(0, would_total - total_actual)
-        arena._local_stats.dedup_rows += sum(
-            m - n for _, m, n in region_rows)
         return True
 
     def _copy_rows(self, sl, rows: np.ndarray) -> np.ndarray:

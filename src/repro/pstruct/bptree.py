@@ -253,13 +253,11 @@ class BPTree:
         f0 = int(hv[H_FRESH_RECS])
         recs = self._alloc_recs(len(nks))
         self.records.vol[recs, :VALUE_WORDS] = nvs
-        # fresh-range record slots sit above the committed watermark, so
-        # shadow mode flushes them home in place; free-list reuses may
-        # have been freed by a still-uncommitted delete (live in the
-        # committed image) and must route through the shadow remap
+        # fresh-range slots and free-list reuses are two marks (the
+        # write set's marks / saved_lines counters count them apart)
         fr = recs[recs >= f0]
         if fr.size:
-            self.records.mark_rows(fr, fresh=True)
+            self.records.mark_rows(fr)
         rew = recs[recs < f0]
         if rew.size:
             self.records.mark_rows(rew)

@@ -203,14 +203,12 @@ class DoublyLinkedList:
         if self.snapshot:
             self._snap_dirty[self._r1 - n:self._r1] = True
         # ---- mark dirty (flushed once at epoch close) ----
-        # fresh-range ids sit above the committed fresh-water mark, so
-        # their bytes are dead in the committed image: shadow mode may
-        # flush them home in place (unreachable until the flip), while
-        # free-list reuses and the old tail's pointer rewrite must route
-        # through the shadow remap
+        # fresh-range ids, then free-list reuses plus the old tail's
+        # pointer rewrite: two marks (the write set's marks /
+        # saved_lines counters count them apart)
         new = ids[ids >= fresh0]
         if new.size:
-            self.nodes.mark_rows(new, fresh=True)
+            self.nodes.mark_rows(new)
         reused = ids[ids < fresh0]
         dirty = reused if old_tail == NULL \
             else np.concatenate([[old_tail], reused])

@@ -215,12 +215,7 @@ class Hashmap:
             if self.mode == "full":
                 self.entries.vol[ids, 8] = h.astype(np.int64) >> np.int64(1)
                 # chain pointers persisted too (set in _link)
-            # new ids come off the fresh-range watermark, so their slab
-            # bytes are dead in the committed image: shadow mode flushes
-            # them home in place (unreachable until the flip); a
-            # same-epoch update re-marks the row as a rewrite and the
-            # writeset's rewrite-wins rule reroutes it through the remap
-            self.entries.mark_rows(ids, fresh=True)
+            self.entries.mark_rows(ids)
             if hv[H_SIZE] > self.load_factor * self.n_buckets:
                 self._grow()
         hv[H_FLAG] = 1

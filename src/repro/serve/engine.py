@@ -72,10 +72,6 @@ class EngineConfig:
     # hashes across shards, and the paged-KV metadata arena shards too —
     # recovery re-admits traffic per (shard, prompt-length) group.
     n_shards: int = 1
-    # Commit protocol of the host persistence substrate: "barrier" pays
-    # the two-phase data/metadata ordering each epoch; "shadow" routes
-    # rewrites through shadow banks and pays ONE flip (DESIGN.md §9)
-    commit_mode: str = "barrier"
     # Chain-ranking strategy for every recovery NEXT walk (request-table
     # unlinks, LRU ring scan): doubling vs contraction list ranking
     # (core.recovery.chain_method, DESIGN.md §8)
@@ -126,7 +122,6 @@ class ServingEngine:
         if journal_enabled(cfg.journal):
             layout.update(RequestJournal.layout(jr_cap, name="req"))
         self.arena = open_arena(arena_path, layout, n_shards=cfg.n_shards,
-                                commit_mode=cfg.commit_mode,
                                 paged=cfg.paged, block_bytes=cfg.block_bytes,
                                 cache_blocks=cfg.cache_blocks)
         self.table = Hashmap(self.arena, cfg.max_requests, cfg.mode,
@@ -144,8 +139,7 @@ class ServingEngine:
             n_pages=max(cfg.n_pages or 0,
                         cfg.max_batch * (cfg.s_max // cfg.page_tokens)),
             page_tokens=cfg.page_tokens, mode=cfg.mode,
-            n_shards=cfg.n_shards, commit_mode=cfg.commit_mode,
-            chain_method=cfg.chain_method, snapshot=cfg.snapshot,
+            n_shards=cfg.n_shards, chain_method=cfg.chain_method, snapshot=cfg.snapshot,
             paged=cfg.paged, block_bytes=cfg.block_bytes,
             cache_blocks=cfg.cache_blocks))
         # device state (DERIVABLE)

@@ -62,7 +62,6 @@ class FeatureConfig:
     n_samples: int = 1024         # sample-log capacity
     mode: str = "partly"
     n_shards: int = 1
-    commit_mode: str = "barrier"
     chain_method: str = "auto"
     snapshot: Optional[bool] = None
     journal: Optional[bool] = None
@@ -80,8 +79,7 @@ class FeatureStore:
         jr_cap = 2 * cfg.n_samples
         if journal_enabled(cfg.journal):
             layout.update(RequestJournal.layout(jr_cap, name="emb"))
-        self.arena = open_arena(path, layout, n_shards=cfg.n_shards,
-                                commit_mode=cfg.commit_mode)
+        self.arena = open_arena(path, layout, n_shards=cfg.n_shards)
         self.table = Hashmap(self.arena, cfg.n_keys, cfg.mode, name="emb",
                              chain_method=cfg.chain_method,
                              snapshot=cfg.snapshot)

@@ -18,10 +18,10 @@ Partly-persistent split:
   rebuilt by the registered ``serve.journal`` reconstructor.
 
 MOD-style minimal ordering: the journal adds NO ordering points of its
-own.  Entries are marked ``fresh`` into the enclosing epoch's write set
-(every append targets a slot outside the committed live window — the
-sealing rule — so the shadow drain homes them in place and the barrier
-drain can never tear a committed entry), and visibility follows the
+own.  Entries are marked into the enclosing epoch's write set (every
+append targets a slot outside the committed live window — the sealing
+rule — so the drain can never tear a committed entry), and visibility
+follows the
 SAME convention as every structure header: the persisted HEAD/TAIL
 counters ride a metadata line.  When the journal is hosted by a
 structure whose header line is already marked every epoch (the request
@@ -32,13 +32,10 @@ diverge across any crash point, and the journal's flush overhead is
 exactly the one ring line per epoch counted in
 ``FlushStats.journal_lines``.
 
-Crash-window argument (both commit modes): an entry is visible iff its
-seq is under the committed HEAD.  Barrier mode — the ring line flushes
-in the data phase, HEAD in the metadata phase; a torn (data-only) crash
-leaves the entry bytes behind an unmoved HEAD, invisible.  Shadow mode
-— the fresh ring line homes in place during the unordered drain, but
-the header rewrite sits in the uncommitted mirror bank until the
-generation flip; a pre-flip crash recovers the old header, same result.
+Crash-window argument: an entry is visible iff its seq is under the
+committed HEAD.  The ring line flushes in the data phase, HEAD in the
+metadata phase; a torn (data-only) crash leaves the entry bytes behind
+an unmoved HEAD, invisible.
 A wrap append may overwrite a slot still inside a stale committed
 window, but only RETIRED entries' slots are ever reused (``log``
 refuses when head - tail >= capacity and ``retire_completed`` only
@@ -164,8 +161,8 @@ class RequestJournal:
         row[7] = snap_checksum(row)
         self.ring.vol[slot] = row
         # sealing rule: the slot is outside the committed live window
-        # (only retired slots are ever reused), hence fresh
-        self.ring.mark_rows(np.array([slot]), fresh=True)
+        # (only retired slots are ever reused)
+        self.ring.mark_rows(np.array([slot]))
         hv = self.header.vol[0]
         hv[self._hb] = seq + 1
         hv[self._hb + 1] = self.tail

@@ -30,7 +30,6 @@ class PagedConfig:
     page_tokens: int = 64
     mode: str = "partly"
     n_shards: int = 1      # shard count of the page-metadata arena
-    commit_mode: str = "barrier"   # "barrier" | "shadow" (DESIGN.md §9)
     # chain-ranking strategy for the LRU ring scan after a crash (the
     # DLL reconstructor's NEXT walk): "auto" flips from pointer doubling
     # to contraction list ranking once the page pool crosses the
@@ -65,7 +64,6 @@ class PagedAllocator:
         layout = DoublyLinkedList.layout(cfg.n_pages, cfg.mode, name="lru",
                                          snapshot=cfg.snapshot)
         self.arena = open_arena(path, layout, n_shards=cfg.n_shards,
-                                commit_mode=cfg.commit_mode,
                                 paged=cfg.paged,
                                 block_bytes=cfg.block_bytes,
                                 cache_blocks=cfg.cache_blocks)

@@ -22,7 +22,6 @@ Four invariant families:
   APPROXIMABLE re-warming off the restore critical path without
   changing the restored state.
 """
-import os
 import threading
 
 import numpy as np
@@ -35,26 +34,20 @@ from repro.pstruct.dll import DoublyLinkedList
 from repro.pstruct.hashmap import Hashmap
 
 MODES = ("partly", "full")
-
-# CI matrix axes (DESIGN.md §7, §9): the whole crash/recovery fuzz
-# suite reruns on a sharded substrate with REPRO_N_SHARDS=4 and under
-# the shadow commit protocol with REPRO_COMMIT_MODE=shadow — every
-# invariant here is independent of both the shard count and the
-# commit-ordering protocol.
-N_SHARDS = int(os.environ.get("REPRO_N_SHARDS", "1"))
-COMMIT_MODE = os.environ.get("REPRO_COMMIT_MODE", "barrier")
+# every invariant here is independent of the shard count (DESIGN.md §7),
+# so the arena tests run on one shard and on four
+SHARDS = [1, 4]
 
 
 # ---------------------------------------------------------------- helpers
 
 
-def _mixed_arena(mode, commit_mode=None):
+def _mixed_arena(mode, n_shards):
     layout = {}
     layout.update(DoublyLinkedList.layout(256, mode, name="dll"))
     layout.update(BPTree.layout(256, 1024, mode, name="bt"))
     layout.update(Hashmap.layout(512, mode, name="hm"))
-    a = open_arena(None, layout, n_shards=N_SHARDS,
-                   commit_mode=commit_mode or COMMIT_MODE)
+    a = open_arena(None, layout, n_shards=n_shards)
     return (a, DoublyLinkedList(a, 256, mode, name="dll"),
             BPTree(a, 256, 1024, mode, name="bt"),
             Hashmap(a, 512, mode, name="hm"))
@@ -139,10 +132,11 @@ def _strip_timing(report):
 # ----------------------------------------------- boundary-sweep fuzzing
 
 
+@pytest.mark.parametrize("n_shards", SHARDS)
 @pytest.mark.parametrize("mode", MODES)
 @pytest.mark.parametrize("torn", [False, True])
 @pytest.mark.parametrize("concurrency", [1, 4])
-def test_crash_fuzz_every_boundary(mode, torn, concurrency):
+def test_crash_fuzz_every_boundary(mode, torn, concurrency, n_shards):
     """For every epoch boundary b, crash inside op b+1 (power loss
     mid-epoch, or torn: data half flushed but not metadata), recover
     with the given concurrency, and require the committed generation's
@@ -151,7 +145,7 @@ def test_crash_fuzz_every_boundary(mode, torn, concurrency):
     ops = _script(8, seed=3)
     n = len(ops)
     for boundary in range(n):
-        a, d, t, h = _mixed_arena(mode)
+        a, d, t, h = _mixed_arena(mode, n_shards)
         bt_keys = []
         for i in range(boundary + 1):
             _apply(d, t, h, ops[i])
@@ -183,74 +177,15 @@ def test_crash_fuzz_every_boundary(mode, torn, concurrency):
             np.testing.assert_array_equal(got, bt_vals)
 
 
-# ------------------------------------- commit-mode cross-equality
-
-
-@pytest.mark.parametrize("torn", [False, True])
-def test_commit_modes_recover_identical_logical_state(torn):
-    """DESIGN.md §9: the shadow commit changes WHERE uncommitted bytes
-    live, never what recovery rebuilds.  Crash at every epoch boundary
-    (power-loss and torn flavors) under both commit modes and require
-    the recovered structure state — order, data, committed lookups — to
-    be bit-identical.  Raw region bytes legitimately differ (a torn
-    barrier flush lands in home rows, a torn shadow flush sits in a
-    never-selected mirror bank), so equality is asserted on the
-    structure view, which is what the consistency argument is about."""
-    ops = _script(6, seed=5)
-    n = len(ops)
-    for boundary in range(n):
-        state = {}
-        for cm in ("barrier", "shadow"):
-            a, d, t, h = _mixed_arena("partly", commit_mode=cm)
-            keys = {"bt": [], "hm": []}
-            for i in range(boundary + 1):
-                _apply(d, t, h, ops[i])
-                if ops[i][0] in keys:
-                    keys[ops[i][0]].extend(ops[i][1].tolist())
-                a.commit()
-            gen0 = a.generation
-            if boundary + 1 < n:
-                with a.epoch():
-                    _apply(d, t, h, ops[boundary + 1])
-                    if torn:
-                        a.writeset.flush(include_meta=False)
-                    a.crash()
-            else:
-                a.crash()
-            rep = _manager(a, d, t, h).recover(concurrency=2)
-            assert rep.valid and rep.generation == gen0
-            order = d.to_list()
-            st = {"dll.order": order.copy(),
-                  "dll.data": d.data[order].copy(),
-                  "hm.size": np.int64(h.size)}
-            for kind, struct_ in (("bt", t), ("hm", h)):
-                if keys[kind]:
-                    ok, vals = struct_.find_batch(
-                        np.asarray(keys[kind], np.int64))
-                    assert ok.all(), f"{cm}: committed {kind} key lost"
-                    st[f"{kind}.vals"] = vals.copy()
-            state[cm] = st
-            # the shadow protocol has no torn-rewrite asymmetry: keys of
-            # the crashed epoch are gone, not half-surfaced (the barrier
-            # B+Tree may expose them — its in-place leaf rewrite)
-            if cm == "shadow" and boundary + 1 < n \
-                    and ops[boundary + 1][0] == "bt":
-                ok, _ = t.find_batch(ops[boundary + 1][1])
-                assert not ok.any()
-        assert state["barrier"].keys() == state["shadow"].keys()
-        for k in state["barrier"]:
-            np.testing.assert_array_equal(
-                state["shadow"][k], state["barrier"][k],
-                err_msg=f"boundary={boundary}: {k}")
-
-
 # --------------------------------------------- double-failure fuzzing
 
 
+@pytest.mark.parametrize("n_shards", SHARDS)
 @pytest.mark.parametrize("torn", [False, True])
 @pytest.mark.parametrize("concurrency", [1, 4])
 @pytest.mark.parametrize("crash_after_stage", [0, 1, 2, 3])
-def test_double_failure_mid_stage(torn, concurrency, crash_after_stage):
+def test_double_failure_mid_stage(torn, concurrency, crash_after_stage,
+                                  n_shards):
     """Recovery is itself crashed: a listener injects arena.crash() the
     moment the k-th stage report lands (stage 0 = reopen) — under
     concurrency>1 sibling stages of the same level are mid-flight in
@@ -258,7 +193,7 @@ def test_double_failure_mid_stage(torn, concurrency, crash_after_stage):
     raise or produce garbage volatile state; it must never touch
     persistent bytes, so a second, uninterrupted recovery lands on the
     committed fingerprint."""
-    a, d, t, h = _mixed_arena("partly")
+    a, d, t, h = _mixed_arena("partly", n_shards)
     for op in _script(6, seed=11):
         _apply(d, t, h, op)
         a.commit()
@@ -298,8 +233,9 @@ def test_double_failure_mid_stage(torn, concurrency, crash_after_stage):
 # ------------------------------------------------- report truthfulness
 
 
-def test_report_valid_true_only_after_commit(rng):
-    a, d, t, h = _mixed_arena("partly")
+@pytest.mark.parametrize("n_shards", SHARDS)
+def test_report_valid_true_only_after_commit(rng, n_shards):
+    a, d, t, h = _mixed_arena("partly", n_shards)
     d.append_batch(rng.integers(0, 9, (4, 7)))
     a.crash()                                  # commit() never ran
     rep = _manager(a, d, t, h).recover(concurrency=4)
@@ -311,8 +247,9 @@ def test_report_valid_true_only_after_commit(rng):
     assert rep.valid and rep.generation == 1
 
 
-def test_report_valid_false_after_invalidate(rng):
-    a, d, t, h = _mixed_arena("partly")
+@pytest.mark.parametrize("n_shards", SHARDS)
+def test_report_valid_false_after_invalidate(rng, n_shards):
+    a, d, t, h = _mixed_arena("partly", n_shards)
     d.append_batch(rng.integers(0, 9, (4, 7)))
     a.commit()
     a.invalidate()
@@ -324,12 +261,13 @@ def test_report_valid_false_after_invalidate(rng):
 # ------------------------------------------------------- determinism
 
 
+@pytest.mark.parametrize("n_shards", SHARDS)
 @pytest.mark.parametrize("mode", MODES)
 @pytest.mark.parametrize("seed", [0, 7])
-def test_concurrent_recovery_bit_identical_to_serial(mode, seed):
+def test_concurrent_recovery_bit_identical_to_serial(mode, seed, n_shards):
     """recover(concurrency=4) == recover(concurrency=1): bit-identical
     arenas + volatile redundancy, equivalent reports modulo timing."""
-    a, d, t, h = _mixed_arena(mode)
+    a, d, t, h = _mixed_arena(mode, n_shards)
     for op in _script(9, seed=seed):
         _apply(d, t, h, op)
         a.commit()
@@ -347,8 +285,9 @@ def test_concurrent_recovery_bit_identical_to_serial(mode, seed):
 # -------------------------------------------------- callbacks + timing
 
 
-def test_stage_callbacks_fire_once_per_stage_any_thread(rng):
-    a, d, t, h = _mixed_arena("partly")
+@pytest.mark.parametrize("n_shards", SHARDS)
+def test_stage_callbacks_fire_once_per_stage_any_thread(rng, n_shards):
+    a, d, t, h = _mixed_arena("partly", n_shards)
     for op in _script(5, seed=2):
         _apply(d, t, h, op)
         a.commit()
@@ -366,8 +305,9 @@ def test_stage_callbacks_fire_once_per_stage_any_thread(rng):
     assert [s.name for s in rep.stages] == ["reopen", "dll", "bt", "hm"]
 
 
-def test_report_carries_wall_critical_path_and_sum(rng):
-    a, d, t, h = _mixed_arena("partly")
+@pytest.mark.parametrize("n_shards", SHARDS)
+def test_report_carries_wall_critical_path_and_sum(rng, n_shards):
+    a, d, t, h = _mixed_arena("partly", n_shards)
     for op in _script(5, seed=4):
         _apply(d, t, h, op)
         a.commit()
@@ -658,20 +598,16 @@ def test_ckpt_warmup_thread_failure_surfaces(tmp_path, monkeypatch):
 # recover, then replay the ENTIRE workload through the journal's
 # duplicate check — completed requests must be refused, interrupted
 # ones must retry, and the final effect-set must equal a twin run that
-# never crashed.  Swept over both commit modes and shard counts
-# regardless of the ambient CI axes.
+# never crashed.  Swept over one shard and four.
 
 from repro.serve.feature_store import FeatureConfig, FeatureStore  # noqa: E402
 from repro.serve.journal import (ST_DONE, ST_NEVER,  # noqa: E402
                                  ST_RETRY, DuplicateRequestError)
 
-FS_GRID = [("barrier", 1), ("barrier", 4), ("shadow", 1), ("shadow", 4)]
 
-
-def _fs_cfg(commit_mode, n_shards):
+def _fs_cfg(n_shards):
     return FeatureConfig(n_keys=64, dim=3, n_samples=512,
-                         commit_mode=commit_mode, n_shards=n_shards,
-                         journal=True)
+                         n_shards=n_shards, journal=True)
 
 
 def _fs_script(n_ops, seed=0):
@@ -700,10 +636,10 @@ def _fs_assert_effects(fs, want):
     np.testing.assert_array_equal(got["vectors"], want["vectors"])
 
 
-def _fs_twin(commit_mode, n_shards, ops):
+def _fs_twin(n_shards, ops):
     """Uninterrupted twin run: the expected effect-set, plus the journal
     overhead bound (<= 1 extra flushed line per epoch)."""
-    fs = FeatureStore(_fs_cfg(commit_mode, n_shards))
+    fs = FeatureStore(_fs_cfg(n_shards))
     s0 = fs.arena.stats.snapshot()
     for op in ops:
         assert fs.apply(*op)
@@ -712,14 +648,14 @@ def _fs_twin(commit_mode, n_shards, ops):
     return _fs_effects(fs)
 
 
-@pytest.mark.parametrize("commit_mode,n_shards", FS_GRID)
+@pytest.mark.parametrize("n_shards", SHARDS)
 @pytest.mark.parametrize("torn", [False, True])
-def test_journal_exactly_once_every_boundary(commit_mode, n_shards, torn):
+def test_journal_exactly_once_every_boundary(n_shards, torn):
     ops = _fs_script(6, seed=13)
-    want = _fs_twin(commit_mode, n_shards, ops)
+    want = _fs_twin(n_shards, ops)
     last = len(ops) if not torn else len(ops) - 1
     for boundary in range(last + 1):
-        fs = FeatureStore(_fs_cfg(commit_mode, n_shards))
+        fs = FeatureStore(_fs_cfg(n_shards))
         for op in ops[:boundary]:
             assert fs.apply(*op)
         if torn and boundary < len(ops):
@@ -744,18 +680,17 @@ def test_journal_exactly_once_every_boundary(commit_mode, n_shards, torn):
         _fs_assert_effects(fs, want)
 
 
-@pytest.mark.parametrize("commit_mode,n_shards", [("barrier", 1),
-                                                  ("shadow", 4)])
+@pytest.mark.parametrize("n_shards", SHARDS)
 @pytest.mark.parametrize("crash_after_stage", [0, 1, 2, 3, 4])
-def test_journal_oracle_survives_double_failure(commit_mode, n_shards,
+def test_journal_oracle_survives_double_failure(n_shards,
                                                 crash_after_stage):
     """Crash the journal's own recovery after every stage (reopen, emb,
     samples, journal, store — possibly while siblings run in pool
     threads), recover again, and the replay oracle must still land on
     the twin effect-set with zero duplicate admissions."""
     ops = _fs_script(5, seed=21)
-    want = _fs_twin(commit_mode, n_shards, ops)
-    fs = FeatureStore(_fs_cfg(commit_mode, n_shards))
+    want = _fs_twin(n_shards, ops)
+    fs = FeatureStore(_fs_cfg(n_shards))
     for op in ops[:3]:
         assert fs.apply(*op)
     assert fs.apply(*ops[3], _torn_crash=True) is False

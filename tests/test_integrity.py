@@ -8,8 +8,8 @@ Invariant families:
   (``core.arena.mix_checksums``);
 * detection: a single flipped bit or stuck-at line in any COMMITTED
   data row is caught by ``Arena.scrub()`` (and by the paged fault path
-  before a corrupt block is admitted), across both commit modes, 1 and
-  4 shards, paged and resident — with zero false positives on clean
+  before a corrupt block is admitted), across 1 and 4 shards, paged
+  and resident — with zero false positives on clean
   arenas at every commit point (scrub under live traffic);
 * corruption x crash double failure: a crash (power-loss or torn
   flavor) composed with a media fault must end detected-or-harmless —
@@ -22,8 +22,6 @@ Invariant families:
   arena; the serving layers refuse exactly the quarantined keys
   (``QuarantinedError``) until readmitted.
 """
-import os
-
 import numpy as np
 import pytest
 
@@ -38,23 +36,18 @@ from repro.pstruct.dll import DoublyLinkedList
 from repro.pstruct.hashmap import H_FRESH, KEY_NULL, Hashmap
 from repro.serve.journal import _batch_cksum
 
-N_SHARDS = int(os.environ.get("REPRO_N_SHARDS", "1"))
-COMMIT_MODE = os.environ.get("REPRO_COMMIT_MODE", "barrier")
-
-GRID = [("barrier", 1), ("barrier", 4), ("shadow", 1), ("shadow", 4)]
+SHARDS = [1, 4]
 
 
 # ---------------------------------------------------------------- helpers
 
 
-def _mixed(path, mode="partly", commit_mode=None, n_shards=None, **kw):
+def _mixed(path, n_shards, mode="partly", **kw):
     layout = {}
     layout.update(DoublyLinkedList.layout(256, mode, name="dll"))
     layout.update(BPTree.layout(256, 1024, mode, name="bt"))
     layout.update(Hashmap.layout(512, mode, name="hm"))
-    a = open_arena(path, layout,
-                   n_shards=N_SHARDS if n_shards is None else n_shards,
-                   commit_mode=commit_mode or COMMIT_MODE, **kw)
+    a = open_arena(path, layout, n_shards=n_shards, **kw)
     return (a, DoublyLinkedList(a, 256, mode, name="dll"),
             BPTree(a, 256, 1024, mode, name="bt"),
             Hashmap(a, 512, mode, name="hm"))
@@ -147,13 +140,11 @@ def test_checksum_zero_is_reserved_sentinel():
 # ----------------------------------------------------- detection
 
 
-@pytest.mark.parametrize("commit_mode,n_shards", GRID)
+@pytest.mark.parametrize("n_shards", SHARDS)
 @pytest.mark.parametrize("paged", [False, True])
-def test_scrub_detects_flip_and_stuck_line(tmp_path, commit_mode,
-                                           n_shards, paged):
+def test_scrub_detects_flip_and_stuck_line(tmp_path, n_shards, paged):
     kw = dict(paged=True, block_bytes=256, cache_blocks=8) if paged else {}
-    a, d, t, h = _mixed(str(tmp_path / "a.pm"), commit_mode=commit_mode,
-                        n_shards=n_shards, **kw)
+    a, d, t, h = _mixed(str(tmp_path / "a.pm"), n_shards, **kw)
     _run(a, d, t, h, _script(12, seed=1))
     row = int(d.order()[2])
     a.crash()
@@ -173,11 +164,9 @@ def test_scrub_detects_flip_and_stuck_line(tmp_path, commit_mode,
     assert off >= 0
 
 
-@pytest.mark.parametrize("commit_mode", ["barrier", "shadow"])
-def test_paged_fault_path_verifies_blocks(tmp_path, commit_mode):
-    a, d, t, h = _mixed(str(tmp_path / "a.pm"), commit_mode=commit_mode,
-                        n_shards=1, paged=True, block_bytes=256,
-                        cache_blocks=4)
+def test_paged_fault_path_verifies_blocks(tmp_path):
+    a, d, t, h = _mixed(str(tmp_path / "a.pm"), 1, paged=True,
+                        block_bytes=256, cache_blocks=4)
     _run(a, d, t, h, _script(12, seed=2))
     row = int(d.order()[1])
     a.crash()
@@ -198,8 +187,7 @@ def test_integrity_off_layout_and_bytes_are_identical(tmp_path):
     ops = _script(10, seed=3)
     arenas = {}
     for integ in (False, True):
-        a, d, t, h = _mixed(str(tmp_path / f"i{int(integ)}.pm"),
-                            commit_mode="barrier", n_shards=1,
+        a, d, t, h = _mixed(str(tmp_path / f"i{int(integ)}.pm"), 1,
                             integrity=integ)
         _run(a, d, t, h, ops)
         arenas[integ] = a
@@ -221,14 +209,12 @@ def test_integrity_off_layout_and_bytes_are_identical(tmp_path):
 # --------------------------------------- scrub under live traffic
 
 
-@pytest.mark.parametrize("commit_mode,n_shards", GRID)
-def test_scrub_under_traffic_no_false_positives(tmp_path, commit_mode,
-                                                n_shards):
-    """Data and sidecar always move in the same flush phase/bank, so a
+@pytest.mark.parametrize("n_shards", SHARDS)
+def test_scrub_under_traffic_no_false_positives(tmp_path, n_shards):
+    """Data and sidecar always move in the same flush phase, so a
     scrub between ANY two commits — and after any crash point — must
     come back clean."""
-    a, d, t, h = _mixed(str(tmp_path / "a.pm"), commit_mode=commit_mode,
-                        n_shards=n_shards)
+    a, d, t, h = _mixed(str(tmp_path / "a.pm"), n_shards)
     for i, op in enumerate(_script(10, seed=4)):
         with a.epoch():
             _apply(d, t, h, op)
@@ -244,8 +230,7 @@ def test_mid_scrub_crash_is_harmless(tmp_path):
     """Scrub is pure reads: crashing between per-region verify calls
     leaves nothing behind — recovery and a full re-scrub behave exactly
     as if the interrupted scrub never ran."""
-    a, d, t, h = _mixed(str(tmp_path / "a.pm"), commit_mode="barrier",
-                        n_shards=1)
+    a, d, t, h = _mixed(str(tmp_path / "a.pm"), 1)
     _run(a, d, t, h, _script(8, seed=5))
     covered = [n for n, r in a.regions.items() if r._integ is not None]
     assert len(covered) >= 2
@@ -260,10 +245,9 @@ def test_mid_scrub_crash_is_harmless(tmp_path):
 # -------------------------- corruption x crash double failure sweep
 
 
-@pytest.mark.parametrize("commit_mode,n_shards", GRID)
+@pytest.mark.parametrize("n_shards", SHARDS)
 @pytest.mark.parametrize("torn", [False, True])
-def test_corruption_crash_double_failure(tmp_path, commit_mode, n_shards,
-                                         torn):
+def test_corruption_crash_double_failure(tmp_path, n_shards, torn):
     """Satellite sweep: compose a crash (power-loss or torn data-phase
     flavor) with a one-byte media fault in a data region and require
     DETECTED-OR-BIT-IDENTICAL — either scrub names the corruption, or
@@ -287,15 +271,13 @@ def test_corruption_crash_double_failure(tmp_path, commit_mode, n_shards,
 
     for boundary in (3, len(ops) - 1):
         # twin A: same crash, no corruption
-        a, d, t, h = _mixed(str(tmp_path / f"tw{boundary}.pm"),
-                            commit_mode=commit_mode, n_shards=n_shards)
+        a, d, t, h = _mixed(str(tmp_path / f"tw{boundary}.pm"), n_shards)
         _crash(a, d, t, h, boundary)
         _manager(a, d, t, h).recover()
         ref = _fingerprint(d, t, h)
         for j, (reg, row) in enumerate(targets):
             b, d2, t2, h2 = _mixed(
-                str(tmp_path / f"b{boundary}.{j}.pm"),
-                commit_mode=commit_mode, n_shards=n_shards)
+                str(tmp_path / f"b{boundary}.{j}.pm"), n_shards)
             _crash(b, d2, t2, h2, boundary)
             fi.flip_bits(b, b.regions[reg], row, byte=3, mask=0x80)
             rep = _manager(b, d2, t2, h2).recover(salvage=True)
@@ -319,7 +301,7 @@ def test_corruption_crash_double_failure(tmp_path, commit_mode, n_shards,
 
 def test_shard_loss_errors(tmp_path):
     path = str(tmp_path / "s.pm")
-    a, d, t, h = _mixed(path, commit_mode="barrier", n_shards=4)
+    a, d, t, h = _mixed(path, 4)
     _run(a, d, t, h, _script(8, seed=7))
     layout = {}
     layout.update(DoublyLinkedList.layout(256, "partly", name="dll"))
@@ -328,16 +310,15 @@ def test_shard_loss_errors(tmp_path):
     del a, d, t, h
     fi.truncate_shard(path, shard=2, nbytes=64)
     with pytest.raises(ShardLossError):
-        open_arena(path, layout, n_shards=4, commit_mode="barrier")
+        open_arena(path, layout, n_shards=4)
     fi.remove_shard(path, shard=2)
     with pytest.raises(ShardLossError):
-        open_arena(path, layout, n_shards=4, commit_mode="barrier")
+        open_arena(path, layout, n_shards=4)
 
 
 @pytest.mark.parametrize("n_shards", [1, 4])
 def test_manifest_errors(tmp_path, n_shards):
-    a, d, t, h = _mixed(str(tmp_path / "m.pm"), commit_mode="barrier",
-                        n_shards=n_shards)
+    a, d, t, h = _mixed(str(tmp_path / "m.pm"), n_shards)
     _run(a, d, t, h, _script(6, seed=8))
     a.crash()
     if n_shards > 1:
@@ -358,15 +339,13 @@ def test_manifest_errors(tmp_path, n_shards):
 # --------------------------------------------------------- salvage
 
 
-@pytest.mark.parametrize("commit_mode,n_shards", GRID)
+@pytest.mark.parametrize("n_shards", SHARDS)
 @pytest.mark.parametrize("victim", ["dll", "bt", "hm"])
-def test_mixed_salvage_recovers_the_rest(tmp_path, commit_mode, n_shards,
-                                         victim):
+def test_mixed_salvage_recovers_the_rest(tmp_path, n_shards, victim):
     """Acceptance: one corrupted slab of a mixed three-structure arena
     quarantines/degrades ONLY its own stage; the other two recover to
     their exact pre-crash state, and the report names the loss."""
-    a, d, t, h = _mixed(str(tmp_path / "a.pm"), commit_mode=commit_mode,
-                        n_shards=n_shards)
+    a, d, t, h = _mixed(str(tmp_path / "a.pm"), n_shards)
     _run(a, d, t, h, _script(30, seed=9))
     dll_order = d.order().copy()
     bt_keys = t.keys_in_order().copy()
@@ -409,8 +388,7 @@ def test_mixed_salvage_recovers_the_rest(tmp_path, commit_mode, n_shards,
 
 
 def test_full_mode_tree_quarantines_wholesale(tmp_path):
-    a, d, t, h = _mixed(str(tmp_path / "a.pm"), mode="full",
-                        commit_mode="barrier", n_shards=1)
+    a, d, t, h = _mixed(str(tmp_path / "a.pm"), 1, mode="full")
     _run(a, d, t, h, _script(30, seed=10))
     dll_order = d.order().copy()
     leaf = int(t.leaves()[0])
@@ -426,8 +404,7 @@ def test_salvage_off_still_aborts_nothing_silently(tmp_path):
     behavior (possibly recovering garbage the scrub then names) — but
     nothing is EVER silent: on a paged arena the verifying fault path
     raises mid-recovery, on a resident one the scrub names the row."""
-    a, d, t, h = _mixed(str(tmp_path / "a.pm"), commit_mode="barrier",
-                        n_shards=1)
+    a, d, t, h = _mixed(str(tmp_path / "a.pm"), 1)
     _run(a, d, t, h, _script(12, seed=11))
     row = int(d.order()[1])
     a.crash()
@@ -444,8 +421,7 @@ def test_salvage_off_still_aborts_nothing_silently(tmp_path):
 def test_quarantined_dependents_skip(tmp_path):
     """A stage whose dependency quarantined self-skips with a degraded
     report instead of reconstructing from untrusted inputs."""
-    a, d, t, h = _mixed(str(tmp_path / "a.pm"), mode="full",
-                        commit_mode="barrier", n_shards=1)
+    a, d, t, h = _mixed(str(tmp_path / "a.pm"), 1, mode="full")
     _run(a, d, t, h, _script(12, seed=12))
     leaf = int(t.leaves()[0])
     a.crash()
@@ -464,11 +440,11 @@ def test_quarantined_dependents_skip(tmp_path):
 # ------------------------------------------------- serving quarantine
 
 
-def test_feature_store_refuses_only_quarantined_keys(tmp_path):
+@pytest.mark.parametrize("n_shards", SHARDS)
+def test_feature_store_refuses_only_quarantined_keys(tmp_path, n_shards):
     from repro.serve.feature_store import FeatureConfig, FeatureStore
     fs = FeatureStore(FeatureConfig(n_keys=64, dim=3, n_samples=256,
-                                    commit_mode=COMMIT_MODE,
-                                    n_shards=N_SHARDS),
+                                    n_shards=n_shards),
                       str(tmp_path / "fs.pm"))
     rng = np.random.default_rng(13)
     for rid in range(8):
@@ -523,8 +499,7 @@ def test_engine_rejects_only_quarantined_rids(tmp_path):
     params = model.init_params(jax.random.PRNGKey(0))
     eng = ServingEngine(model, params,
                         EngineConfig(max_batch=3, s_max=16,
-                                     max_requests=16,
-                                     commit_mode=COMMIT_MODE),
+                                     max_requests=16),
                         arena_path=str(tmp_path / "a"))
     eng.add_request(7, np.array([1, 2, 3], np.int64))
     eng.add_request(8, np.array([4, 5, 6, 9, 2], np.int64))

@@ -3,15 +3,14 @@ the feature store's replay reconstructor (serve/feature_store.py).
 
 Covered here:
 
-* append / classify roundtrip across crash+recover, both commit modes
-  and shard counts (CI env axes);
+* append / classify roundtrip across crash+recover, on one shard and
+  on four;
 * the admission state machine: duplicate ADMIT/APPLY refused,
   COMPLETE without ADMIT refused, appends outside an epoch refused,
   ring-full refused until ``retire_completed`` frees slots;
 * crash-window visibility: a torn (data-phase-only) append recovers as
-  never-admitted in barrier mode and a pre-flip crash does the same in
-  shadow mode — the entry bytes may be durable, the committed HEAD is
-  not past them;
+  never-admitted — the entry bytes may be durable, the committed HEAD
+  is not past them;
 * the sealing rule: a wrapped append may destroy a RETIRED entry's
   slot without committing; recovery must skip the seq-mismatched slot
   and an orphaned COMPLETE must still classify its rid as completed;
@@ -21,8 +20,6 @@ Covered here:
   the journal-on run minus exactly the ring lines — the overhead bound
   (<= 1 journal line per epoch) the CI matrix asserts.
 """
-import os
-
 import numpy as np
 import pytest
 
@@ -34,16 +31,14 @@ from repro.serve.journal import (JR_MAGIC, OP_ADMIT, OP_APPLY, OP_COMPLETE,
                                  DuplicateRequestError, RequestJournal,
                                  args_digest, snap_checksum)
 
-N_SHARDS = int(os.environ.get("REPRO_N_SHARDS", "1"))
-COMMIT_MODE = os.environ.get("REPRO_COMMIT_MODE", "barrier")
+SHARDS = [1, 4]
 
 
-def _jr(cap=64, commit_mode=None):
+def _jr(n_shards, cap=64):
     """Standalone journal (own .jrnlheader line) on a fresh arena."""
     a = open_arena(None, RequestJournal.layout(cap, name="jr",
                                                standalone=True),
-                   n_shards=N_SHARDS,
-                   commit_mode=commit_mode or COMMIT_MODE)
+                   n_shards=n_shards)
     return a, RequestJournal(a, cap, name="jr")
 
 
@@ -60,8 +55,9 @@ def _recover(a, j):
 # ------------------------------------------------------------ state machine
 
 
-def test_roundtrip_classify_across_crash():
-    a, j = _jr()
+@pytest.mark.parametrize("n_shards", SHARDS)
+def test_roundtrip_classify_across_crash(n_shards):
+    a, j = _jr(n_shards)
     with a.epoch():
         j.log(OP_ADMIT, 1, digest=args_digest([1, 2, 3]))
         j.log(OP_ADMIT, 2)
@@ -78,8 +74,9 @@ def test_roundtrip_classify_across_crash():
     assert j.state_of(99) == ST_NEVER
 
 
-def test_duplicate_admission_raises():
-    a, j = _jr()
+@pytest.mark.parametrize("n_shards", SHARDS)
+def test_duplicate_admission_raises(n_shards):
+    a, j = _jr(n_shards)
     with a.epoch():
         j.log(OP_ADMIT, 5)
         a.commit()
@@ -97,22 +94,25 @@ def test_duplicate_admission_raises():
         a.commit()
 
 
-def test_complete_without_admit_raises():
-    a, j = _jr()
+@pytest.mark.parametrize("n_shards", SHARDS)
+def test_complete_without_admit_raises(n_shards):
+    a, j = _jr(n_shards)
     with a.epoch():
         with pytest.raises(KeyError):
             j.log(OP_COMPLETE, 7)
         a.commit()
 
 
-def test_log_outside_epoch_refused():
-    a, j = _jr()
+@pytest.mark.parametrize("n_shards", SHARDS)
+def test_log_outside_epoch_refused(n_shards):
+    a, j = _jr(n_shards)
     with pytest.raises(AssertionError):
         j.log(OP_ADMIT, 1)
 
 
-def test_unknown_op_refused():
-    a, j = _jr()
+@pytest.mark.parametrize("n_shards", SHARDS)
+def test_unknown_op_refused(n_shards):
+    a, j = _jr(n_shards)
     with a.epoch():
         with pytest.raises(ValueError):
             j.log(0, 1)
@@ -122,11 +122,12 @@ def test_unknown_op_refused():
 # ------------------------------------------------------------ crash windows
 
 
-def test_torn_append_recovers_as_never_admitted():
+@pytest.mark.parametrize("n_shards", SHARDS)
+def test_torn_append_recovers_as_never_admitted(n_shards):
     """Data-phase-only flush: the ring line may be durable but the
     committed HEAD is not past it — the op must classify never-admitted
-    (this is the exactly-once crash window, both commit modes)."""
-    a, j = _jr()
+    (this is the exactly-once crash window)."""
+    a, j = _jr(n_shards)
     with a.epoch():
         j.log(OP_ADMIT, 1)
         a.commit()
@@ -145,8 +146,9 @@ def test_torn_append_recovers_as_never_admitted():
     assert j.state_of(2) == ST_RETRY
 
 
-def test_uncommitted_epoch_recovers_clean():
-    a, j = _jr()
+@pytest.mark.parametrize("n_shards", SHARDS)
+def test_uncommitted_epoch_recovers_clean(n_shards):
+    a, j = _jr(n_shards)
     with a.epoch():
         j.log(OP_ADMIT, 1)
         a.commit()
@@ -158,8 +160,9 @@ def test_uncommitted_epoch_recovers_clean():
     assert j.classify() == {1: ST_RETRY}
 
 
-def test_recover_twice_is_idempotent():
-    a, j = _jr()
+@pytest.mark.parametrize("n_shards", SHARDS)
+def test_recover_twice_is_idempotent(n_shards):
+    a, j = _jr(n_shards)
     with a.epoch():
         j.log(OP_ADMIT, 1)
         j.log(OP_APPLY, 2)
@@ -174,8 +177,9 @@ def test_recover_twice_is_idempotent():
 # ------------------------------------------------- ring wrap + sealing rule
 
 
-def test_ring_full_then_retire_and_wrap():
-    a, j = _jr(cap=4)
+@pytest.mark.parametrize("n_shards", SHARDS)
+def test_ring_full_then_retire_and_wrap(n_shards):
+    a, j = _jr(n_shards, cap=4)
     for rid in range(4):
         with a.epoch():
             j.log(OP_APPLY, rid)
@@ -200,8 +204,9 @@ def test_ring_full_then_retire_and_wrap():
     assert j.state_of(0) == ST_NEVER     # retired: out of the window
 
 
-def test_retire_inside_epoch_refused():
-    a, j = _jr()
+@pytest.mark.parametrize("n_shards", SHARDS)
+def test_retire_inside_epoch_refused(n_shards):
+    a, j = _jr(n_shards)
     with a.epoch():
         j.log(OP_APPLY, 1)
         with pytest.raises(AssertionError):
@@ -209,12 +214,13 @@ def test_retire_inside_epoch_refused():
         a.commit()
 
 
-def test_sealing_rule_skips_destroyed_retired_slot():
+@pytest.mark.parametrize("n_shards", SHARDS)
+def test_sealing_rule_skips_destroyed_retired_slot(n_shards):
     """A wrapped TORN append destroys slot 0's retired first-lap entry
     while the committed window still spans it (ADMIT retired, its
     COMPLETE not yet).  Recovery must skip the seq-mismatched slot and
     the orphaned COMPLETE must still classify rid 0 as completed."""
-    a, j = _jr(cap=4)
+    a, j = _jr(n_shards, cap=4)
     with a.epoch():
         j.log(OP_ADMIT, 0)       # seq 0 -> slot 0
         j.log(OP_ADMIT, 1)       # seq 1
@@ -238,8 +244,9 @@ def test_sealing_rule_skips_destroyed_retired_slot():
     assert j.state_of(5) == ST_NEVER
 
 
-def test_checksum_rejects_corrupt_entry():
-    a, j = _jr()
+@pytest.mark.parametrize("n_shards", SHARDS)
+def test_checksum_rejects_corrupt_entry(n_shards):
+    a, j = _jr(n_shards)
     with a.epoch():
         j.log(OP_ADMIT, 1)
         j.log(OP_ADMIT, 2)
@@ -275,13 +282,13 @@ def _fs_workload(fs, n_ops=6, seed=3):
         assert fs.apply(rid, keys, deltas)
 
 
-def test_journal_off_layout_and_traffic_identical():
+@pytest.mark.parametrize("n_shards", SHARDS)
+def test_journal_off_layout_and_traffic_identical(n_shards):
     """REPRO_JOURNAL=0 layouts must be bit-identical to the pre-journal
     engine: no .jrnl regions, shared regions at unchanged offsets, and
     the journal's entire flush overhead isolated in
     ``FlushStats.journal_lines`` (<= 1 line per epoch)."""
-    cfg_kw = dict(n_keys=32, dim=3, n_samples=256, n_shards=N_SHARDS,
-                  commit_mode=COMMIT_MODE)
+    cfg_kw = dict(n_keys=32, dim=3, n_samples=256, n_shards=n_shards)
     on = FeatureStore(FeatureConfig(journal=True, **cfg_kw))
     off = FeatureStore(FeatureConfig(journal=False, **cfg_kw))
     assert on.journal is not None and off.journal is None

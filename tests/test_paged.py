@@ -6,7 +6,7 @@ Invariant families:
   through the cache — headers, order snapshots, and journal rings stay
   resident; paging is strictly volatile-side, so a paged arena's
   persistent files are BYTE-identical to an unpaged arena's for the
-  same op trace (both commit modes, sharded and single);
+  same op trace (sharded and single);
 * LRU discipline: clean blocks evict at the budget, dirty blocks are
   pinned until the write-set drain (the epoch flush IS the write-back
   path) — an all-pinned cache goes over budget rather than drop the
@@ -16,7 +16,7 @@ Invariant families:
 * eviction + write-back under crash sweeps: forced post-commit drops
   (``drop_clean``) and organic tiny-cache eviction never change what
   recovery reconstructs — fingerprints match the unpaged reference at
-  every epoch boundary, in both commit modes;
+  every epoch boundary;
 * the spill fallback (full-``.vol`` consumers) is correct, counted,
   and exits paged mode until the next load;
 * recovery reports per-stage ``block_faults`` on paged arenas.
@@ -28,9 +28,6 @@ from repro.core.arena import open_arena
 from repro.core.paging import BlockCache, PagedRegion, PagedShardedRegion
 from repro.core.recovery import RecoveryManager
 from repro.pstruct.dll import DoublyLinkedList
-
-MODES = ("barrier", "shadow")
-
 
 def _paged_kw(cache_blocks=4, block_bytes=512):
     return dict(paged=True, block_bytes=block_bytes,
@@ -190,19 +187,17 @@ def _dll_fingerprint(d):
     return order.copy(), d.data[order].copy()
 
 
-@pytest.mark.parametrize("mode", MODES)
 @pytest.mark.parametrize("n_shards", [1, 3])
-def test_persistent_files_bit_identical_paged_vs_unpaged(
-        tmp_path, mode, n_shards):
+def test_persistent_files_bit_identical_paged_vs_unpaged(tmp_path, n_shards):
     """Paging is volatile-only: the same op trace must land the same
-    bytes in every backing file (shards + manifest), either mode."""
+    bytes in every backing file (shards + manifest)."""
     blobs = {}
     for paged in (False, True):
         root = tmp_path / f"paged{int(paged)}"
         root.mkdir()
         ap = str(root / "a")
         a = open_arena(ap, DoublyLinkedList.layout(256, "partly"),
-                       n_shards=n_shards, commit_mode=mode,
+                       n_shards=n_shards,
                        **(_paged_kw() if paged else {"paged": False}))
         d = DoublyLinkedList(a, 256, "partly")
         _dll_trace(a, d, 6)
@@ -215,8 +210,7 @@ def test_persistent_files_bit_identical_paged_vs_unpaged(
             f"{name} diverged under paging"
 
 
-@pytest.mark.parametrize("mode", MODES)
-def test_evict_then_crash_sweep_every_epoch_boundary(tmp_path, mode):
+def test_evict_then_crash_sweep_every_epoch_boundary(tmp_path):
     """At every epoch boundary: commit, force-drop every clean block,
     run an uncommitted tail, crash.  Recovery must reconstruct the
     boundary's committed state bit-identically to an unpaged reference
@@ -224,9 +218,8 @@ def test_evict_then_crash_sweep_every_epoch_boundary(tmp_path, mode):
     for k in range(1, 6):
         fps = {}
         for paged in (False, True):
-            ap = str(tmp_path / f"{mode}.{k}.{int(paged)}")
+            ap = str(tmp_path / f"{k}.{int(paged)}")
             a = open_arena(ap, DoublyLinkedList.layout(96, "partly"),
-                           commit_mode=mode,
                            **(_paged_kw(cache_blocks=3) if paged
                               else {"paged": False}))
             d = DoublyLinkedList(a, 96, "partly")
@@ -241,13 +234,12 @@ def test_evict_then_crash_sweep_every_epoch_boundary(tmp_path, mode):
         np.testing.assert_array_equal(fps[False][1], fps[True][1])
 
 
-@pytest.mark.parametrize("mode", MODES)
-def test_organic_eviction_crash_recovery(tmp_path, mode):
+def test_organic_eviction_crash_recovery(tmp_path):
     """A cache far smaller than the working set evicts continuously
     during the trace (no forced drops); recovery is still exact."""
     ap = str(tmp_path / "a")
     a = open_arena(ap, DoublyLinkedList.layout(96, "partly"),
-                   commit_mode=mode, **_paged_kw(cache_blocks=2))
+                   **_paged_kw(cache_blocks=2))
     d = DoublyLinkedList(a, 96, "partly")
     _dll_trace(a, d, 8, crash_tail=True)
     assert a.cache.evictions > 0, "cache never evicted — not exercised"
@@ -258,8 +250,7 @@ def test_organic_eviction_crash_recovery(tmp_path, mode):
     fp0 = _dll_fingerprint(d)
     # unpaged reference
     a2 = open_arena(str(tmp_path / "b"),
-                    DoublyLinkedList.layout(96, "partly"),
-                    commit_mode=mode, paged=False)
+                    DoublyLinkedList.layout(96, "partly"), paged=False)
     d2 = DoublyLinkedList(a2, 96, "partly")
     _dll_trace(a2, d2, 8, crash_tail=True)
     a2.crash()

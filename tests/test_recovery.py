@@ -11,7 +11,6 @@
   must recover it for the count-bounded structures (DLL, Hashmap) and a
   valid superset state for the in-place-rewriting B+Tree.
 """
-import os
 
 import numpy as np
 import pytest
@@ -309,15 +308,12 @@ def test_manager_reports_uncommitted_arena_invalid(rng):
 # --------------------------------------------------- torn-epoch recovery
 
 
-def _mixed_arena(mode):
-    # REPRO_N_SHARDS reruns the torn-epoch sweep on a sharded substrate
-    # (the CI matrix axis, DESIGN.md §7)
+def _mixed_arena(mode, n_shards):
     layout = {}
     layout.update(DoublyLinkedList.layout(256, mode, name="dll"))
     layout.update(BPTree.layout(256, 1024, mode, name="bt"))
     layout.update(Hashmap.layout(512, mode, name="hm"))
-    a = open_arena(None, layout,
-                   n_shards=int(os.environ.get("REPRO_N_SHARDS", "1")))
+    a = open_arena(None, layout, n_shards=n_shards)
     return (a, DoublyLinkedList(a, 256, mode, name="dll"),
             BPTree(a, 256, 1024, mode, name="bt"),
             Hashmap(a, 512, mode, name="hm"))
@@ -369,9 +365,13 @@ def _recover_all(a, d, t, h):
     return mgr.recover()
 
 
+# the torn-epoch sweep runs on one shard and on a sharded substrate
+# (DESIGN.md §7)
+@pytest.mark.parametrize("n_shards", [1, 4])
 @pytest.mark.parametrize("mode", MODES)
 @pytest.mark.parametrize("torn", [False, True])
-def test_crash_at_every_epoch_boundary_recovers_committed_state(mode, torn):
+def test_crash_at_every_epoch_boundary_recovers_committed_state(mode, torn,
+                                                                n_shards):
     """Replay a 12-op mixed workload; for every boundary b, crash during
     op b+1 — either before anything flushed (torn=False: power loss
     mid-epoch) or after the data half flushed but not the metadata half
@@ -380,7 +380,7 @@ def test_crash_at_every_epoch_boundary_recovers_committed_state(mode, torn):
     ops = _script(12)
     n = len(ops)
     for boundary in range(n):
-        a, d, t, h = _mixed_arena(mode)
+        a, d, t, h = _mixed_arena(mode, n_shards)
         bt_keys, hm_keys = [], []
         snap = None
         for i in range(boundary + 1):
@@ -427,14 +427,15 @@ def test_crash_at_every_epoch_boundary_recovers_committed_state(mode, torn):
             assert got["bt_count"] == snap["bt_count"]
 
 
-def test_torn_bptree_leaf_rewrite_is_visible_but_durable(rng):
+@pytest.mark.parametrize("n_shards", [1, 4])
+def test_torn_bptree_leaf_rewrite_is_visible_but_durable(rng, n_shards):
     """Documents the asymmetry the boundary sweep allows for: a B+Tree
     insert rewrites committed leaf rows in place, so the data half of a
     torn epoch IS reachable after recovery — committed keys stay durable
     with committed values, but the torn keys surface and the committed
     COUNT goes stale (which is why check_invariants is not owed here,
     unlike the count-bounded DLL/Hashmap)."""
-    a, d, t, h = _mixed_arena("partly")
+    a, d, t, h = _mixed_arena("partly", n_shards)
     keys = np.arange(40, dtype=np.int64)
     vals = rng.integers(0, 9, (40, 7)).astype(np.int64)
     t.insert_batch(keys, vals)

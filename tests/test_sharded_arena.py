@@ -132,13 +132,12 @@ def test_per_shard_stats_sum_to_aggregate(rng):
 # ------------------------------------------- shard-count invariance
 
 
-def _mixed(n_shards, mode="partly", commit_mode="barrier"):
+def _mixed(n_shards, mode="partly"):
     layout = {}
     layout.update(DoublyLinkedList.layout(256, mode, name="dll"))
     layout.update(BPTree.layout(256, 1024, mode, name="bt"))
     layout.update(Hashmap.layout(512, mode, name="hm"))
-    a = open_arena(None, layout, n_shards=n_shards,
-                   commit_mode=commit_mode)
+    a = open_arena(None, layout, n_shards=n_shards)
     return (a, DoublyLinkedList(a, 256, mode, name="dll"),
             BPTree(a, 256, 1024, mode, name="bt"),
             Hashmap(a, 512, mode, name="hm"))
@@ -247,23 +246,14 @@ def test_intershard_commit_window_recovers_agreed_generation(
     assert a.header_generation() == 7 and a.header_valid()
 
 
-@pytest.mark.parametrize(
-    "commit_mode,crash_after_shard",
-    # the -1 window (post-seal / pre-flip) exists only in shadow mode,
-    # so the grid enumerates valid (mode, window) pairs instead of a
-    # full product with a perpetual skip for barrier/-1
-    [("barrier", k) for k in range(4)]
-    + [("shadow", k) for k in (-1, 0, 1, 2, 3)])
-def test_commit_window_sweep_both_modes(commit_mode, crash_after_shard):
-    """The inter-shard commit-window sweep, rerun under both commit
-    protocols.  ``crash_after_shard=k>=0`` powers off after shard k's
-    header flipped but before the manifest; ``-1`` is shadow-only — the
-    torn-flip window's leading edge, after every shard SEALED its
-    target bank but before any header flip.  Either way the manifest
-    names the generation all shards agree on and recovery lands where a
-    flushed-but-uncommitted crash lands."""
+@pytest.mark.parametrize("crash_after_shard", range(4))
+def test_commit_window_sweep(crash_after_shard):
+    """The inter-shard commit-window sweep: ``crash_after_shard=k``
+    powers off after shard k's header flipped but before the manifest.
+    The manifest names the generation all shards agree on and recovery
+    lands where a flushed-but-uncommitted crash lands."""
     def build():
-        a, d, t, h = _mixed(4, commit_mode=commit_mode)
+        a, d, t, h = _mixed(4)
         _trace(a, d, t, h, n_ops=6)
         d.append_batch(np.ones((3, 7), np.int64))
         return a, d, t, h
@@ -285,65 +275,6 @@ def test_commit_window_sweep_both_modes(commit_mode, crash_after_shard):
     # not wedged: the next commit seals gen 7 everywhere
     a.commit()
     assert a.header_generation() == 7 and a.header_valid()
-
-
-@pytest.mark.parametrize("n_shards", [1, 4])
-def test_shadow_gc_crash_is_idempotent(n_shards):
-    """Double failure inside shadow-bank reclamation: the fold of the
-    committed bank's rows back into their home slots is interrupted
-    mid-region (limit=1), power fails, recovery reruns — twice in a
-    row.  The fold only ever writes committed values over dead bytes,
-    so the committed fingerprint must never move and the substrate must
-    still commit afterwards."""
-    a, d, t, h = _mixed(n_shards, commit_mode="shadow")
-    _trace(a, d, t, h, n_ops=6)
-    a.crash()
-    _recover(a, d, t, h)
-    want = _fingerprint(a, d, t, h)
-    for _ in range(2):
-        for sh in (a.shards if hasattr(a, "shards") else [a]):
-            sh._shadow_collapse(limit=1)   # partial fold ...
-        a.crash()                          # ... then power loss
-        rep = _recover(a, d, t, h)
-        assert rep.valid and rep.generation == 6
-        got = _fingerprint(a, d, t, h)
-        for k in want:
-            np.testing.assert_array_equal(got[k], want[k], err_msg=k)
-    d.append_batch(np.ones((2, 7), np.int64))
-    a.commit()
-    assert a.header_generation() == 7 and a.header_valid()
-
-
-def test_single_arena_sealed_unflipped_discards_epoch():
-    """Plain-Arena flavor of the torn-flip window: the commit sequence
-    runs through collapse + drain + seal, then crashes before the
-    generation flip.  The sealed target bank is orphaned — recovery
-    reads the committed bank and the epoch vanishes whole."""
-    def build():
-        a, d, t, h = _mixed(1, commit_mode="shadow")
-        _trace(a, d, t, h, n_ops=4)
-        d.append_batch(np.ones((3, 7), np.int64))  # drained on close
-        return a, d, t, h
-
-    # reference: same epoch drained, commit never started
-    a, d, t, h = build()
-    a.crash()
-    _recover(a, d, t, h)
-    want = _fingerprint(a, d, t, h)
-    # fuzzed: run commit's sub-steps up to the seal, crash pre-flip
-    a2, d2, t2, h2 = build()
-    a2._shadow_collapse()
-    a2.writeset.flush()
-    a2._shadow_seal()
-    a2.crash()
-    rep = _recover(a2, d2, t2, h2)
-    assert rep.valid and rep.generation == 4
-    got = _fingerprint(a2, d2, t2, h2)
-    for k in want:
-        np.testing.assert_array_equal(got[k], want[k], err_msg=k)
-    d2.append_batch(np.ones((2, 7), np.int64))
-    a2.commit()
-    assert a2.header_generation() == 5 and a2.header_valid()
 
 
 def test_manifest_is_written_last_on_disk(tmp_path):

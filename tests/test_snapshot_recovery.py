@@ -3,14 +3,11 @@ sweep, suffix-only replay, env gating, accounting isolation, and the
 device-side verify.
 
 The torn-record sweep is the crash-point fuzzer's snapshot axis: power
-fails mid-snapshot-append at every epoch boundary, under both commit
-protocols, and — via the REPRO_N_SHARDS env axis the CI matrix drives —
-on a sharded substrate.  Recovery must refuse the torn snapshot
+fails mid-snapshot-append at every epoch boundary, on one shard and on
+a sharded substrate.  Recovery must refuse the torn snapshot
 (verify-always adoption) and land on EXACTLY the state a full
 contraction rebuild recovers.
 """
-import os
-
 import numpy as np
 import pytest
 
@@ -20,19 +17,17 @@ from repro.core.recovery import ChainSnapshot, RecoveryManager, chain_order
 from repro.pstruct.dll import DoublyLinkedList, _reconstruct_dll
 from repro.pstruct.hashmap import Hashmap, _reconstruct_hashmap
 
-N_SHARDS = int(os.environ.get("REPRO_N_SHARDS", "1"))
-MODES = ["barrier", "shadow"]
+SHARDS = [1, 4]
 
 
 # ----------------------------------------------------------- helpers
 
-def _build(commit_mode, n_shards=N_SHARDS, snapshot=True):
+def _build(n_shards, snapshot=True):
     layout = {}
     layout.update(DoublyLinkedList.layout(256, name="dll",
                                           snapshot=snapshot))
     layout.update(Hashmap.layout(512, name="hm", snapshot=snapshot))
-    a = open_arena(None, layout, n_shards=n_shards,
-                   commit_mode=commit_mode)
+    a = open_arena(None, layout, n_shards=n_shards)
     return (a, DoublyLinkedList(a, 256, name="dll", snapshot=snapshot),
             Hashmap(a, 512, name="hm", snapshot=snapshot))
 
@@ -100,9 +95,9 @@ def _assert_state(d, h, hm_keys, want):
 
 # ----------------------------------- torn-snapshot-record crash sweep
 
-@pytest.mark.parametrize("mode", MODES)
+@pytest.mark.parametrize("n_shards", SHARDS)
 @pytest.mark.parametrize("tear", ["record", "all"])
-def test_torn_snapshot_record_sweep(mode, tear):
+def test_torn_snapshot_record_sweep(n_shards, tear):
     """Crash mid-snapshot-append at EVERY epoch boundary: the newest
     record line lands garbled ("record") or the whole record ring plus
     half the mirror lands garbled ("all").  Verify-always adoption must
@@ -111,7 +106,7 @@ def test_torn_snapshot_record_sweep(mode, tear):
     fallback."""
     ops = _script(12)
     for boundary in range(len(ops)):
-        a, d, h = _build(mode)
+        a, d, h = _build(n_shards)
         hm_keys, dll_ids = [], []
         for i in range(boundary + 1):
             _apply(d, h, ops[i], dll_ids)
@@ -144,11 +139,11 @@ def test_torn_snapshot_record_sweep(mode, tear):
 
 # ------------------------------------------------- suffix-only replay
 
-@pytest.mark.parametrize("mode", MODES)
-def test_suffix_replay_length_matches_delta(mode):
+@pytest.mark.parametrize("n_shards", SHARDS)
+def test_suffix_replay_length_matches_delta(n_shards):
     """Tear only the newest record: recovery seeds from the previous
     record and replays exactly the rows committed after it."""
-    a, d, h = _build(mode)
+    a, d, h = _build(n_shards)
     d.append_batch(np.arange(280).reshape(40, 7).astype(np.int64))
     a.commit()
     k = np.arange(50, dtype=np.int64)
@@ -173,8 +168,9 @@ def test_suffix_replay_length_matches_delta(mode):
     _assert_state(d, h, k.tolist() + (k + 100).tolist(), want)
 
 
-def test_clean_recovery_adopts_without_replay():
-    a, d, h = _build("barrier")
+@pytest.mark.parametrize("n_shards", SHARDS)
+def test_clean_recovery_adopts_without_replay(n_shards):
+    a, d, h = _build(n_shards)
     d.append_batch(np.arange(70).reshape(10, 7).astype(np.int64))
     k = np.arange(30, dtype=np.int64)
     h.insert_batch(k, np.tile(k[:, None], (1, 7)))
@@ -192,7 +188,7 @@ def test_persisted_record_tear_survives_restart():
     """Tear the record at the PERSISTED layer (no reliance on the
     volatile load path) and reconstruct through fresh objects — the
     cross-process shape of the fuzzer."""
-    a, d, h = _build("barrier", n_shards=1)
+    a, d, h = _build(1)
     d.append_batch(np.arange(70).reshape(10, 7).astype(np.int64))
     a.commit()
     d.append_batch(np.ones((5, 7), np.int64))
@@ -226,13 +222,14 @@ def test_env_gate_and_layout_parity(monkeypatch):
     assert {n for n in on} - {n for n in off} == {"x.snapring", "x.snaprec"}
 
 
-def test_snapshot_off_recovery_identical_states():
+@pytest.mark.parametrize("n_shards", SHARDS)
+def test_snapshot_off_recovery_identical_states(n_shards):
     """The REPRO_SNAPSHOT=0 rerun axis: recovered structure state must
     be identical with snapshots on and off (the snapshot is pure
     derivable redundancy)."""
     states = {}
     for snap in (True, False):
-        a, d, h = _build("barrier", snapshot=snap)
+        a, d, h = _build(n_shards, snapshot=snap)
         hm_keys, dll_ids = [], []
         for op in _script(8):
             _apply(d, h, op, dll_ids)
@@ -255,13 +252,14 @@ def test_snapshot_off_recovery_identical_states():
 
 # ------------------------------------------------ accounting isolation
 
-def test_snapshot_lines_accounted_separately():
+@pytest.mark.parametrize("n_shards", SHARDS)
+def test_snapshot_lines_accounted_separately(n_shards):
     """snapshot_lines is a separate counter: data lines / bytes / dedup
     savings are bit-comparable between snapshot-on and snapshot-off runs
     of the same workload."""
     stats = {}
     for snap in (True, False):
-        a, d, h = _build("barrier", n_shards=N_SHARDS, snapshot=snap)
+        a, d, h = _build(n_shards, snapshot=snap)
         hm_keys, dll_ids = [], []
         for op in _script(10, seed=3):
             _apply(d, h, op, dll_ids)
@@ -278,8 +276,9 @@ def test_snapshot_lines_accounted_separately():
 
 # --------------------------------------------- manager stage details
 
-def test_manager_stage_detail_reports_chain():
-    a, d, h = _build("barrier")
+@pytest.mark.parametrize("n_shards", SHARDS)
+def test_manager_stage_detail_reports_chain(n_shards):
+    a, d, h = _build(n_shards)
     d.append_batch(np.arange(70).reshape(10, 7).astype(np.int64))
     k = np.arange(20, dtype=np.int64)
     h.insert_batch(k, np.tile(k[:, None], (1, 7)))
